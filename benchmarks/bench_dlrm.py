@@ -36,6 +36,12 @@ from repro.serving import hot_cache as HC
 import numpy as np
 
 
+def _kernel_impl() -> str:
+    """Kernels compile natively on TPU; elsewhere the interpreter runs
+    them."""
+    return "pallas" if jax.default_backend() == "tpu" else "interpret"
+
+
 def _timeit(fn, *args, reps=10):
     fn(*args)
     t0 = time.perf_counter()
@@ -129,8 +135,7 @@ def measure_fused(batch=256, cache_rows=16, csv=True):
     _, miss_mask = HC.lookup(cache, idx, mask)
 
     # --- pooled-lookup stage time: reference vs Pallas kernel ---
-    kernel_backend = "pallas" if jax.default_backend() == "tpu" \
-        else "interpret"
+    kernel_backend = _kernel_impl()
     lookups = {
         "ref": jax.jit(lambda i, m: D.apply_emb(tables, i, m, "ref")),
         kernel_backend: jax.jit(
@@ -478,8 +483,10 @@ def stream_parity_smoke():
     mask = (jax.random.uniform(ks[2], (b, t, hot)) < 0.7) \
         .astype(jnp.float32)
     want = ref.embedding_bag_stacked_ref(tbl, idx, mask)
-    resident = ops.embedding_bag_stacked_op(tbl, idx, mask, row_block=-1)
-    streamed = ops.embedding_bag_stacked_op(tbl, idx, mask, row_block=rb)
+    resident = ops.embedding_bag_stacked_op(tbl, idx, mask, row_block=-1,
+                                            impl=_kernel_impl())
+    streamed = ops.embedding_bag_stacked_op(tbl, idx, mask, row_block=rb,
+                                            impl=_kernel_impl())
     d = float(jnp.max(jnp.abs(np.asarray(streamed) - np.asarray(resident))))
     assert d <= 1e-6, f"streamed kernel diverged from resident by {d}"
     assert np.array_equal(np.asarray(streamed), np.asarray(want)), \
@@ -509,9 +516,11 @@ def vector_pool_smoke():
     want = ref.embedding_bag_stacked_ref(tbl, idx, mask)
     fns = {
         "resident_scalar": jax.jit(lambda i, m: ops.embedding_bag_stacked_op(
-            tbl, i, m, row_block=-1, pool_mode="scalar")),
+            tbl, i, m, row_block=-1, pool_mode="scalar",
+            impl=_kernel_impl())),
         "resident_vector": jax.jit(lambda i, m: ops.embedding_bag_stacked_op(
-            tbl, i, m, row_block=-1, pool_mode="vector")),
+            tbl, i, m, row_block=-1, pool_mode="vector",
+            impl=_kernel_impl())),
         # the real DMA pipeline in both pool modes (interpret machinery
         # executes the async-copy schedule standalone)
         "streamed_scalar": lambda i, m: eb.embedding_bag_stacked(

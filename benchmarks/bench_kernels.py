@@ -9,6 +9,12 @@ import jax
 import jax.numpy as jnp
 
 
+def _kernel_impl() -> str:
+    """Kernels compile natively on TPU; elsewhere the interpreter runs
+    them."""
+    return "pallas" if jax.default_backend() == "tpu" else "interpret"
+
+
 def _timeit(fn, *args, reps=5):
     fn(*args)
     t0 = time.perf_counter()
@@ -116,15 +122,17 @@ def bench_embedding_bag(csv=True, batch=128):
         fns = {"ref": lambda: ops.embedding_bag_stacked_op(
                    tbl, idx, mask, impl="ref"),
                "streamed": lambda: ops.embedding_bag_stacked_op(
-                   tbl, idx, mask, row_block=rb)}
+                   tbl, idx, mask, row_block=rb, impl=_kernel_impl())}
         if resident_ok:
             # resident kernel in BOTH pool modes: the scalar-vs-vector
             # A/B the pool_mode knob exists for ('resident' = vector,
             # what 'auto' dispatches)
             fns["resident"] = lambda: ops.embedding_bag_stacked_op(
-                tbl, idx, mask, row_block=-1, pool_mode="vector")
+                tbl, idx, mask, row_block=-1, pool_mode="vector",
+                impl=_kernel_impl())
             fns["resident_scalar"] = lambda: ops.embedding_bag_stacked_op(
-                tbl, idx, mask, row_block=-1, pool_mode="scalar")
+                tbl, idx, mask, row_block=-1, pool_mode="scalar",
+                impl=_kernel_impl())
         for fn in fns.values():
             fn()                                   # compile off the clock
         # interleaved min-of-trials (the bench_dlrm._best_paired idea): a
@@ -146,7 +154,8 @@ def bench_embedding_bag(csv=True, batch=128):
         else:
             entry["resident"] = "exceeds_vmem"     # R·s·4 B > budget
             try:
-                ops.embedding_bag_stacked_op(tbl, idx, mask, row_block=-1)
+                ops.embedding_bag_stacked_op(tbl, idx, mask, row_block=-1,
+                                             impl=_kernel_impl())
                 raise AssertionError("resident kernel accepted an "
                                      "oversized table block")
             except ValueError:

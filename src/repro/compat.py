@@ -1,82 +1,20 @@
-"""JAX version-compat shims.
+"""Mesh construction with Auto axis types.
 
-The repo targets the modern public API (``jax.shard_map``, ``jax.make_mesh``
-with ``axis_types``); older installs (<= 0.4.x) carry the same functionality
-under ``jax.experimental.shard_map`` / without axis types.  Every mesh or
-shard_map construction in repo code and tests goes through this module so a
-single file owns the version probe.
+``jax.make_mesh`` defaults to Explicit axis types; every mesh in repo code
+and tests shards through ``shard_map`` and sharding constraints, which want
+Auto axes, so mesh construction goes through :func:`make_mesh`.
 """
 from __future__ import annotations
 
-from typing import Optional, Sequence
+from typing import Sequence
 
 import jax
-from jax.sharding import Mesh
-
-
-def _auto_axis_types(n: int) -> dict:
-    at = getattr(jax.sharding, "AxisType", None)
-    if at is None:
-        return {}
-    return {"axis_types": (at.Auto,) * n}
+from jax.sharding import AxisType, Mesh
 
 
 def make_mesh(axis_shapes: Sequence[int], axis_names: Sequence[str], *,
               devices=None) -> Mesh:
-    """``jax.make_mesh`` with Auto axis types when the install has them;
-    falls back to mesh_utils + Mesh on installs without jax.make_mesh."""
-    if hasattr(jax, "make_mesh"):
-        try:
-            return jax.make_mesh(tuple(axis_shapes), tuple(axis_names),
-                                 devices=devices,
-                                 **_auto_axis_types(len(axis_names)))
-        except TypeError:
-            return jax.make_mesh(tuple(axis_shapes), tuple(axis_names),
-                                 devices=devices)
-    from jax.experimental import mesh_utils
-    devs = mesh_utils.create_device_mesh(tuple(axis_shapes),
-                                         devices=devices)
-    return mesh_from(devs, axis_names)
-
-
-def mesh_from(device_array, axis_names: Sequence[str]) -> Mesh:
-    """``Mesh(devices, names)`` with Auto axis types when available."""
-    try:
-        return Mesh(device_array, tuple(axis_names),
-                    **_auto_axis_types(len(axis_names)))
-    except TypeError:
-        return Mesh(device_array, tuple(axis_names))
-
-
-def shard_map(f, *, mesh, in_specs, out_specs, check_vma: bool = False):
-    """``jax.shard_map`` (new) or experimental shard_map (old).
-
-    ``check_vma`` maps onto the old API's ``check_rep``; both default off
-    here because the DLRM/MoE shard functions use manual collectives whose
-    replication the checker cannot see through.
-    """
-    if hasattr(jax, "shard_map"):
-        try:
-            return jax.shard_map(f, mesh=mesh, in_specs=in_specs,
-                                 out_specs=out_specs, check_vma=check_vma)
-        except TypeError:
-            pass  # transitional versions spell the flag check_rep
-    from jax.experimental.shard_map import shard_map as _shard_map
-    return _shard_map(f, mesh=mesh, in_specs=in_specs, out_specs=out_specs,
-                      check_rep=check_vma)
-
-
-def compiler_params_kw(dimension_semantics: tuple) -> dict:
-    """``compiler_params=`` kwarg for a TPU ``pallas_call`` across the
-    TPUCompilerParams -> CompilerParams rename; empty when neither
-    exists."""
-    from jax.experimental.pallas import tpu as pltpu
-    cp = getattr(pltpu, "CompilerParams", None) or \
-        getattr(pltpu, "TPUCompilerParams", None)
-    if cp is None:
-        return {}
-    return {"compiler_params": cp(dimension_semantics=dimension_semantics)}
-
-
-def default_device_count() -> int:
-    return len(jax.devices())
+    """``jax.make_mesh`` with Auto axis types."""
+    return jax.make_mesh(tuple(axis_shapes), tuple(axis_names),
+                         axis_types=(AxisType.Auto,) * len(axis_names),
+                         devices=devices)

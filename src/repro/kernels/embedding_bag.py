@@ -1,46 +1,63 @@
 """Pallas TPU kernels: embedding-bag gather + masked pooling (DLRM apply_emb).
 
 The paper's flame graph (Fig. 5) shows apply_emb dominating DLRM inference;
-this is its TPU form.  Two regimes, one knob (``row_block``, DESIGN.md §1):
+this is its TPU form.
 
-* **VMEM-resident** — the whole ``(R, s)`` table block rides a BlockSpec into
-  VMEM and the (sample × hot) index list is pooled straight out of it: the
-  HBM->VMEM->VREG path FBGEMM's TBE takes on GPU, re-expressed for the TPU
-  memory hierarchy.  Only sound while ``R · s · itemsize`` fits the VMEM
-  budget (rows ≲ 16k at s=64 f32).
+**Table view.**  On the TPU a ``(T, R, s)`` f32 stack with ``s < 128``
+lives in HBM with the row axis minor (XLA's layout ``{1,2,0}``): every
+table is physically an ``(s, R)`` matrix whose COLUMNS are embedding rows.
+The kernels consume exactly that view — ``jnp.swapaxes(tables, 1, 2)``,
+a free bitcast on the chip — so no padded (R, 128) copy of the stack is
+ever made (for the Kaggle stack that copy alone would not fit a 16 GB
+chip).  One embedding row is one lane column; gathering it means loading
+the aligned 128-lane tile that holds it and rotating the column into
+place (:func:`_stage_col`).  DMAs move whole 128-lane tiles, so a
+natively-lowered streamed kernel needs ``R`` and the block height to be
+multiples of 128 (``init_dlrm`` pads R; the padding is what the layout
+holds anyway).
 
-* **DMA-streamed** — production-size tables (the capacity-driven scale-out
-  regime of PAPERS.md) cannot be resident, so the table stays in HBM
-  (``memory_space=ANY``) and the kernel streams ``row_block``-row chunks
-  through TWO VMEM scratch slots with ``pltpu.make_async_copy``: the copy of
-  block *n+1* is in flight while block *n* is pooled.  Indices are
-  pre-bucketed per row block OUTSIDE the kernel (:func:`_stream_plan`):
+Two regimes, one knob (``row_block``, DESIGN.md §1):
+
+* **VMEM-resident** — a whole ``(s, R)`` table rides a BlockSpec into VMEM
+  and the (sample × hot) index list is pooled straight out of it.  Only
+  sound while ``R · s · itemsize`` fits the VMEM budget (rows ≲ 16k at
+  s=64 f32).
+
+* **DMA-streamed** — production-size tables cannot be resident, so the
+  table stays in HBM (``memory_space=ANY``) and the kernel streams
+  ``(s, row_block)`` blocks through TWO VMEM scratch slots with
+  ``pltpu.make_async_copy``: the copy of block *n+1* is in flight while
+  block *n* is pooled.  Blocks never straddle tables (block *k* of table
+  *t* covers rows ``[k·rb, k·rb + rb)`` clamped into the table).  Indices
+  are pre-bucketed per block OUTSIDE the kernel (:func:`_stream_plan`):
   grouping by block id makes each block's indices a contiguous segment of
-  the planned list, and empty blocks are compacted away entirely — each grid
-  step DMAs only the blocks its indices actually touch, so a skewed access
-  pattern (the hot-cache regime) streams a small head instead of the whole
-  table.
+  the planned list, and empty blocks are compacted away entirely — each
+  grid step DMAs only the blocks its indices touch, so a skewed access
+  pattern streams a small head instead of the whole table.
 
-Each regime pools in one of two **pool modes** (``pool_mode``):
+Per-tile plan scalars (row ids, staging slots, weights, block offsets and
+segment bounds) ride in SMEM, where the scalar core can use them as DMA
+offsets and loop bounds.  Each staged row lands in an f32 ``(hot, s,
+128)`` VMEM accumulator — bag ``b``'s ``h``-th index in lane ``b`` of row
+``h`` — written through refs, never through a loop-carried value.  The
+final reduce over ``hot`` runs in the reference order, so every kernel
+form is bit-identical to the jnp oracle in f32 no matter which block
+order the rows arrived in.
 
-* ``scalar`` — a ``fori_loop`` walks every (sample, hot) index doing a
-  one-row dynamic-slice gather (the PR 3 form, kept for A/B and fallback);
-* ``vector`` (the default under ``auto``) — indices are processed in
-  ``POOL_CHUNK``-wide chunks that gather whole ``(chunk, s)`` row tiles in
-  one vector gather and weight them under a validity mask (chunk tail +
-  empty-bag mask folded into the weights), so the staging accumulator fills
-  at vector width instead of one row per iteration.
+Each regime walks its indices in one of two **pool modes** (``pool_mode``):
 
-Both modes and both regimes stage the weighted rows into a ``(tile, hot,
-s)``-equivalent f32 buffer slot-per-index and reduce over ``hot`` at the
-end, reproducing the reference ``jnp.sum`` order — every kernel form is
-bit-identical to the jnp oracle in f32 no matter which block order the rows
-arrived in or how wide the gather ran.
+* ``scalar`` — a ``fori_loop`` over exactly one segment position per
+  step;
+* ``vector`` (the default under ``auto``) — ``chunk`` positions per step,
+  statically unrolled so their independent loads, rotates and stores
+  overlap; a chunk's overhang past its segment stages rows that the
+  owning (later) block overwrites, and overhang past the list writes
+  nothing.
 
 The **stream plan** itself (:func:`_stream_plan`) has two builders behind
-one ``plan_method`` knob: ``sort`` (the PR 3 ``O(L log L)`` argsort by row
-id) and ``count`` (a counting sort keyed by block id: one histogram over
-``nb`` buckets whose prefix sum IS the segment-offset table — ``O(L · nb)``
+one ``plan_method`` knob: ``sort`` (``O(L log L)`` argsort by row id) and
+``count`` (a counting sort keyed by block id: one histogram over ``nb``
+buckets whose prefix sum IS the segment-offset table — ``O(L · nb)``
 vectorized work, no comparison sort); ``auto`` picks ``count`` while
 ``L · nb`` stays under :data:`PLAN_COUNT_WORK` and falls back to ``sort``
 past it.  Plans are plain pytrees (:class:`StreamPlan`), so they can be
@@ -51,11 +68,9 @@ entry point accepts ``plan=`` to consume it, which is how
 stage_a compute (DESIGN.md §1).
 
 Interpret-mode dispatch runs the identical streaming schedule as pure jax
-ops (:func:`_stream_rows_jnp`) by default: this jax version miscompiles
-interpret-mode ``pallas_call`` internals under COMPILED multi-device
-shard_map, so CPU validation inside the distributed forward uses the
-op-level emulation, while the Pallas DMA pipeline itself is validated
-standalone (``dma=True``) and lowers natively on TPU.
+ops (:func:`_stream_rows_jnp`) by default, so CPU validation inside the
+jitted multi-device forward runs plain ops, while the Pallas DMA pipeline
+itself is validated standalone (``dma=True``) and lowers natively on TPU.
 
 Entry points: :func:`embedding_bag` (single table), :func:`embedding_bag_
 stacked` (the (T, R, s) model stack), :func:`embedding_bag_rows` (ragged
@@ -76,33 +91,34 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-# VMEM budgets (bytes).  RESIDENT bounds the one (R, s) table block the
-# resident kernel keeps live per grid step (16 MiB VMEM, minus index/out
-# tiles and headroom -> 4 MiB ~ 16k rows at s=64 f32, the DESIGN.md §1
-# number).  STREAM bounds the streamed kernel's two DMA slots TOGETHER, and
-# STAGE bounds the (tile, hot, s) f32 staging accumulator — the wrappers
-# shrink row_block / batch_tile to respect them.
+# VMEM budgets (bytes).  RESIDENT bounds the one (s, R) table block the
+# resident kernel keeps live per grid step (16 MiB scoped VMEM, minus the
+# staging accumulator and headroom -> 4 MiB ~ 16k rows at s=64 f32, the
+# DESIGN.md §1 number).  STREAM bounds the streamed kernel's two DMA slots
+# TOGETHER, and STAGE bounds the (tile, hot, s) f32 staging work — the
+# wrappers shrink row_block / batch_tile to respect them.
 RESIDENT_VMEM_BYTES = 4 << 20
 STREAM_VMEM_BYTES = 4 << 20
 STAGE_VMEM_BYTES = 2 << 20
 
-# Vector-pool gather width: one (POOL_CHUNK, s) row tile is gathered and
-# weighted per step — the lane width of the pooling inner loop.  Chunk
-# tails past a segment/tile end ride along with weight 0 (validity folded
-# into the weights), so nothing is gathered twice and staging slots still
-# receive exactly one contribution each (bit-exactness is preserved).
-POOL_CHUNK = 128
+# The vreg lane width.  Table rows are lane columns of the (s, R) view,
+# DMAs and tile loads move whole 128-lane tiles, and the staging
+# accumulator keeps one bag per lane, so a tile holds at most LANES bags.
+LANES = 128
+
+# Vector-pool unroll: positions staged per loop step.  A chunk's overhang
+# past its segment is overwritten by the owning block, so a wider chunk
+# only pays off while segments are at least that long.
+POOL_CHUNK = 8
 
 
 def _stream_pool_chunk(L: int, nbmax: int) -> int:
-    """Chunk width for the STREAMED vector pool: the streamed kernel walks
-    per-block segments averaging L / nbmax positions, so a full POOL_CHUNK
-    would gather mostly masked-off tail lanes when blocks are many.  Clamp
-    the chunk to the expected segment length (rounded up to 8 sublanes) —
-    skew only makes hot-block segments longer, which the fori over chunks
-    absorbs."""
-    seg = -(-L // max(nbmax, 1))
-    return max(8, min(POOL_CHUNK, -(-seg // 8) * 8))
+    """Unroll width for the STREAMED vector pool: segments average
+    L / nbmax positions, so a full POOL_CHUNK would stage mostly
+    overhang when blocks are many.  Clamp the chunk to the expected
+    segment length — skew only makes hot-block segments longer, which
+    the fori over chunks absorbs."""
+    return max(1, min(POOL_CHUNK, -(-L // max(nbmax, 1))))
 
 # Counting-sort plan budget: the count method materializes a
 # (tiles, L, nb) one-hot running sum to rank indices within their block
@@ -118,8 +134,9 @@ def fits_resident(rows: int, s: int, itemsize: int) -> bool:
 
 def auto_row_block(total_rows: int, s: int, itemsize: int) -> int:
     """Streamed block height: half the stream budget per DMA slot, rounded
-    down to a multiple of 8 rows, clipped to the table."""
-    rb = max(8, (STREAM_VMEM_BYTES // (2 * s * itemsize)) // 8 * 8)
+    down to whole 128-row lane tiles, clipped to the table."""
+    rb = max(LANES, (STREAM_VMEM_BYTES // (2 * s * itemsize))
+             // LANES * LANES)
     return min(total_rows, rb)
 
 
@@ -180,31 +197,52 @@ class StreamPlan(NamedTuple):
     compacted block index owning each planned position.  Weights are NOT
     part of the plan — they are permuted with ``pos`` at consumption time,
     so a plan built from indices alone (before cache miss-masks exist)
-    stays valid."""
+    stays valid.  Blocks are per table: ``off`` is the flat start row
+    ``t·rows + c`` of a block that lies inside table ``t``."""
     sid: jax.Array     # planned (block-grouped) flat row ids
     pos: jax.Array     # original position of each planned entry
     inv: jax.Array     # planned position of each original entry
-    off: jax.Array     # clamped HBM start row per compacted block
+    off: jax.Array     # clamped flat start row per compacted block
     seg0: jax.Array    # segment start per compacted block
     seg1: jax.Array    # segment end per compacted block
     nblk: jax.Array    # compacted (touched) block count
     cum: jax.Array     # compacted block index per planned position
     rb: int = 0           # static: block height the plan bucketed for
     total_rows: int = 0   # static: flat row-space height
+    rows: int = 0         # static: rows per table (blocks never straddle)
 
 
-N_PLAN_LEAVES = 8          # array fields above; rb/total_rows are aux
+N_PLAN_LEAVES = 8          # array fields above; rb/total_rows/rows are aux
 
-# rb/total_rows are STATIC aux data, not traced leaves: tree transforms
-# (vmap over microbatches, shard_map redistribution, scan slicing) map the
-# eight index arrays and carry the geometry alongside, and _check_plan can
-# raise at trace time when a plan meets a call with a different
-# row_block/table — shapes alone cannot always tell them apart (nbmax
-# clamps to L for any sufficiently tall table).
+# rb/total_rows/rows are STATIC aux data, not traced leaves: tree
+# transforms (vmap over microbatches, shard_map redistribution, scan
+# slicing) map the eight index arrays and carry the geometry alongside,
+# and _check_plan can raise at trace time when a plan meets a call with a
+# different row_block/table — shapes alone cannot always tell them apart
+# (nbmax clamps to L for any sufficiently tall table).
 jax.tree_util.register_pytree_node(
     StreamPlan,
-    lambda p: (tuple(p[:N_PLAN_LEAVES]), (p.rb, p.total_rows)),
+    lambda p: (tuple(p[:N_PLAN_LEAVES]), (p.rb, p.total_rows, p.rows)),
     lambda aux, leaves: StreamPlan(*leaves, *aux))
+
+
+def _n_blocks(total_rows: int, rows: int, rb: int) -> int:
+    """Block count of a stack of ``total_rows // rows`` tables."""
+    return (total_rows // rows) * -(-rows // rb)
+
+
+def _block_of(gid, rb: int, rows: int):
+    """Block id of flat row ids: table-major, ``ceil(rows / rb)`` blocks
+    per table."""
+    return (gid // rows) * -(-rows // rb) + (gid % rows) // rb
+
+
+def _block_start(bid, rb: int, rows: int):
+    """Flat start row of block ``bid``, clamped inside its table so a
+    table whose height is not a multiple of ``rb`` streams an overlapping
+    final block instead of reading the next table."""
+    nbt = -(-rows // rb)
+    return (bid // nbt) * rows + jnp.clip((bid % nbt) * rb, 0, rows - rb)
 
 
 def _resolve_plan_method(plan_method: str, L: int, nb_total: int,
@@ -231,14 +269,15 @@ def _inverse_perm(perm):
         .reshape(tiles, L)
 
 
-def _plan_sort(gid, rb: int, total_rows: int, nbmax: int) -> StreamPlan:
+def _plan_sort(gid, rb: int, total_rows: int, nbmax: int,
+               rows: int) -> StreamPlan:
     """The comparison-sort plan builder (PR 3): argsort by full row id,
     segments recovered by searchsorted over the block-change prefix sum."""
     tiles, L = gid.shape
     pos = jnp.argsort(gid, axis=-1).astype(jnp.int32)
     sid = jnp.take_along_axis(gid, pos, axis=-1)
     inv = _inverse_perm(pos)
-    blk = sid // rb                                        # (tiles, L)
+    blk = _block_of(sid, rb, rows)                         # (tiles, L)
     first = jnp.concatenate(
         [jnp.ones((tiles, 1), bool), blk[:, 1:] != blk[:, :-1]], axis=-1)
     cum = jnp.cumsum(first.astype(jnp.int32), axis=-1) - 1  # compact index
@@ -249,7 +288,7 @@ def _plan_sort(gid, rb: int, total_rows: int, nbmax: int) -> StreamPlan:
     seg1 = jax.vmap(
         lambda c: jnp.searchsorted(c, jr, side="right"))(cum)
     bid = jnp.take_along_axis(blk, jnp.minimum(seg0, L - 1), axis=-1)
-    off = jnp.clip(bid * rb, 0, total_rows - rb)
+    off = _block_start(bid, rb, rows)
     valid = jr[None, :] < nblk
     zero = jnp.zeros((), jnp.int32)
     return StreamPlan(
@@ -257,7 +296,8 @@ def _plan_sort(gid, rb: int, total_rows: int, nbmax: int) -> StreamPlan:
         jnp.where(valid, off, zero).astype(jnp.int32),
         jnp.where(valid, seg0, zero).astype(jnp.int32),
         jnp.where(valid, seg1, zero).astype(jnp.int32),
-        nblk.astype(jnp.int32), cum, rb=rb, total_rows=total_rows)
+        nblk.astype(jnp.int32), cum, rb=rb, total_rows=total_rows,
+        rows=rows)
 
 
 # chunk length of the hierarchical running count below: shortening the
@@ -289,7 +329,8 @@ def _bucket_rank(key, nb_total: int):
     return rank, hist
 
 
-def _plan_count(gid, rb: int, total_rows: int, nbmax: int) -> StreamPlan:
+def _plan_count(gid, rb: int, total_rows: int, nbmax: int,
+                rows: int) -> StreamPlan:
     """The counting-sort plan builder: bucket by block id (``nb_total``
     buckets).  One histogram's prefix sum IS the segment-offset table, and
     the stable within-bucket rank comes from the hierarchical one-hot
@@ -299,8 +340,8 @@ def _plan_count(gid, rb: int, total_rows: int, nbmax: int) -> StreamPlan:
     keyed by original position), so the pooled output is bit-identical to
     the sort plan's."""
     tiles, L = gid.shape
-    nb_total = -(-total_rows // rb)
-    key = gid // rb                                       # (tiles, L)
+    nb_total = _n_blocks(total_rows, rows, rb)
+    key = _block_of(gid, rb, rows)                        # (tiles, L)
     rank, hist = _bucket_rank(key, nb_total)
     excl = jnp.cumsum(hist, axis=-1) - hist               # segment offsets
     dest = jnp.take_along_axis(excl, key, axis=-1) + rank  # (tiles, L)
@@ -325,17 +366,17 @@ def _plan_count(gid, rb: int, total_rows: int, nbmax: int) -> StreamPlan:
     jr = jnp.arange(nbmax, dtype=jnp.int32)
     valid = jr[None, :] < nblk
     zero = jnp.zeros((), jnp.int32)
-    off = jnp.where(valid, jnp.clip(bid * rb, 0, total_rows - rb), zero)
-    cum = jnp.take_along_axis(cidx, sid // rb, axis=-1)
+    off = jnp.where(valid, _block_start(bid, rb, rows), zero)
+    cum = jnp.take_along_axis(cidx, _block_of(sid, rb, rows), axis=-1)
     return StreamPlan(sid, pos, inv, off.astype(jnp.int32),
                       jnp.where(valid, seg0, zero),
                       jnp.where(valid, seg1, zero),
                       nblk, cum.astype(jnp.int32),
-                      rb=rb, total_rows=total_rows)
+                      rb=rb, total_rows=total_rows, rows=rows)
 
 
 def _stream_plan(gid, rb: int, total_rows: int, nbmax: int,
-                 plan_method: str = "auto") -> StreamPlan:
+                 plan_method: str = "auto", rows: int = 0) -> StreamPlan:
     """Pre-bucket a tile batch of indices per row block (the XLA half of
     the streamed kernel).
 
@@ -346,60 +387,67 @@ def _stream_plan(gid, rb: int, total_rows: int, nbmax: int,
     exactly once (total work stays L gathers per tile).  The last block's
     DMA start is clamped to ``total_rows - rb`` so a table whose row count
     is not a multiple of ``rb`` streams an overlapping final block instead
-    of reading out of bounds.
+    of reading the next table (blocks never straddle tables: the kernel
+    DMAs one table's (s, rb) window per block).
 
     ``plan_method``: 'sort' (argsort by row id, O(L log L)), 'count'
     (counting sort keyed by block id, O(L · nb) vectorized), 'auto' (count
-    under :data:`PLAN_COUNT_WORK`, sort past it)."""
+    under :data:`PLAN_COUNT_WORK`, sort past it).  ``rows`` is the height
+    of one table of the stack (0: one table of ``total_rows``)."""
     tiles, L = gid.shape
-    nb_total = -(-total_rows // rb)
+    rows = rows or total_rows
+    nb_total = _n_blocks(total_rows, rows, rb)
     method = _resolve_plan_method(plan_method, L, nb_total, tiles)
     build = _plan_count if method == "count" else _plan_sort
-    return build(gid, rb, total_rows, nbmax)
+    return build(gid, rb, total_rows, nbmax, rows)
 
 
 def _stream_geometry(total_rows: int, s: int, n: int, hot: int,
-                     row_tile: int, rb: int):
+                     row_tile: int, rb: int, rows: int):
     """(nt, tiles, n_pad, L, nbmax, n_slots) — the one tiling both the
     Pallas kernels and the jnp emulation (and any precomputed plan) share,
-    so a plan built outside can never disagree with the executor."""
-    nt = _stage_tile(row_tile, n, hot, s)
+    so a plan built outside can never disagree with the executor.  A tile
+    holds at most LANES bags (one per accumulator lane)."""
+    nt = min(_stage_tile(row_tile, n, hot, s), LANES)
     tiles = -(-n // nt)
     n_pad = tiles * nt
     L = nt * hot
-    nbmax = min(-(-total_rows // rb), L)
+    nbmax = min(_n_blocks(total_rows, rows, rb), L)
     n_slots = min(2, nbmax)       # one whole-table block needs no partner
     return nt, tiles, n_pad, L, nbmax, n_slots
 
 
 def build_stream_plan(total_rows: int, s: int, gid, *, row_tile: int,
-                      rb: int, plan_method: str = "auto") -> StreamPlan:
+                      rb: int, plan_method: str = "auto",
+                      rows: int = 0) -> StreamPlan:
     """Build a :class:`StreamPlan` for ``gid`` (n, hot) pre-clipped flat
     row ids OUTSIDE the kernel call — the off-critical-path half of the
     plan/compute overlap (DESIGN.md §1).  The tiling geometry is exactly
-    what :func:`_stream_rows` derives, so the plan drops in via ``plan=``."""
+    what :func:`_stream_rows` derives, so the plan drops in via ``plan=``.
+    ``rows`` is one table's height (0: a single table)."""
     n, hot = gid.shape
+    rows = rows or total_rows
     nt, tiles, n_pad, L, nbmax, _ = _stream_geometry(
-        total_rows, s, n, hot, row_tile, rb)
+        total_rows, s, n, hot, row_tile, rb, rows)
     if n_pad != n:
         gid = jnp.pad(gid, ((0, n_pad - n), (0, 0)))
     return _stream_plan(gid.reshape(tiles, L).astype(jnp.int32), rb,
-                        total_rows, nbmax, plan_method)
+                        total_rows, nbmax, plan_method, rows)
 
 
 def _check_plan(plan: StreamPlan, tiles: int, L: int, nbmax: int,
-                rb: int, total_rows: int):
-    # rb/total_rows ride the plan as static metadata: leaf shapes alone
-    # cannot always distinguish two block heights (nbmax clamps to L for
-    # any sufficiently tall table), and consuming a plan bucketed for a
+                rb: int, total_rows: int, rows: int):
+    # rb/total_rows/rows ride the plan as static metadata: leaf shapes
+    # alone cannot always distinguish two block heights (nbmax clamps to L
+    # for any sufficiently tall table), and consuming a plan bucketed for a
     # different rb would gather silently-wrong rows
+    meta = ("rb", "total_rows", "rows")
     want = {"sid": (tiles, L), "pos": (tiles, L), "inv": (tiles, L),
             "off": (tiles, nbmax), "seg0": (tiles, nbmax),
             "seg1": (tiles, nbmax), "nblk": (tiles, 1), "cum": (tiles, L),
-            "rb": rb, "total_rows": total_rows}
-    got = {k: tuple(getattr(plan, k).shape)
-           for k in want if k not in ("rb", "total_rows")}
-    got.update(rb=plan.rb, total_rows=plan.total_rows)
+            "rb": rb, "total_rows": total_rows, "rows": rows}
+    got = {k: tuple(getattr(plan, k).shape) for k in want if k not in meta}
+    got.update({k: getattr(plan, k) for k in meta})
     if got != want:
         raise ValueError(
             f"precomputed StreamPlan does not match this call's geometry: "
@@ -408,123 +456,122 @@ def _check_plan(plan: StreamPlan, tiles: int, L: int, nbmax: int,
 
 
 # ---------------------------------------------------------------------------
+# the pooling step shared by every kernel form
+# ---------------------------------------------------------------------------
+
+
+def _stage_col(src, acc, loc, q, w, *, hot: int):
+    """Stage one index: row ``loc`` of ``src`` — a lane column of an (s,
+    width) table view, width >= LANES — weighted by ``w`` into staging
+    slot ``q`` = bag·hot + h, i.e. lane ``bag`` of ``acc[h]``.
+
+    The aligned 128-lane tile holding the column is loaded and rotated so
+    the column lands on lane ``bag``; a lane select writes it there.  A
+    slot with bag >= LANES (list padding) matches no lane and writes
+    nothing."""
+    width = src.shape[-1]
+    base = pl.multiple_of(
+        jnp.minimum(loc // LANES * LANES, width - LANES), LANES)
+    tile = src[:, pl.ds(base, LANES)].astype(jnp.float32)     # (s, LANES)
+    bag, h = q // hot, q % hot
+    moved = pltpu.roll(tile, jax.lax.rem(bag - (loc - base) + LANES, LANES),
+                       1)
+    lane = jax.lax.broadcasted_iota(jnp.int32, tile.shape, 1)
+    acc[h] = jnp.where(lane == bag, moved * w, acc[h])
+
+
+def _walk(lo, hi, pool, chunk: int):
+    """Run ``pool(p)`` over positions [lo, hi): one per step (scalar pool,
+    ``chunk`` 1) or ``chunk`` statically unrolled per step (vector pool —
+    the last step overhangs ``hi``; callers make overhang harmless)."""
+    if chunk == 1:
+        def one(p, carry):
+            pool(p)
+            return carry
+        jax.lax.fori_loop(lo, hi, one, 0)
+        return
+
+    def step(c, carry):
+        base = lo + c * chunk
+        for k in range(chunk):
+            pool(base + k)
+        return carry
+
+    jax.lax.fori_loop(0, pl.cdiv(hi - lo, chunk), step, 0)
+
+
+def _check_lane_aligned(rows: int, rb: int):
+    """The native streamed kernel DMAs whole 128-lane tiles of the (s, R)
+    view: every block start and height must be a lane multiple."""
+    if rows % LANES or rb % LANES:
+        raise ValueError(
+            f"native streamed embedding-bag kernel: table height {rows} and "
+            f"row_block {rb} must both be multiples of {LANES} (DMAs move "
+            f"whole lane tiles of the (s, R) table view) — init_dlrm pads "
+            f"the stack; pick row_block accordingly")
+
+
+# ---------------------------------------------------------------------------
 # the streaming core: pre-bucketed indices + double-buffered DMA
 # ---------------------------------------------------------------------------
 
 
 def _stream_kernel(sid_ref, pos_ref, w_ref, off_ref, seg0_ref, seg1_ref,
-                   nb_ref, tbl_ref, out_ref, buf, sem, *, hot: int,
-                   rb: int):
-    """Double-buffered HBM->VMEM row-block streaming, SCALAR pool.
+                   nb_ref, tbl_ref, out_ref, buf, acc, sem, *, hot: int,
+                   rb: int, rows: int, chunk: int):
+    """Double-buffered HBM->VMEM block streaming over the (T, s, R) table
+    view.
 
-    tbl_ref lives in ANY/HBM; buf is (2, rb, s) VMEM.  Block j+1's
-    ``make_async_copy`` is started before block j's rows are pooled, so
-    the copy engine runs a block ahead of the gather loop.  Each compacted
-    block pools exactly its own segment of the pre-bucketed index list into
-    the (L, s) f32 staging accumulator (slot-per-index), which reduces
-    over ``hot`` at the end — the reference summation order, independent
-    of block arrival order."""
-    nt, s = out_ref.shape
-    l = sid_ref.shape[1]
-    n_slots = buf.shape[0]          # 2, or 1 when only one block can ship
+    Plan refs are (1, ·) SMEM rows of this tile; tbl_ref lives in ANY/HBM;
+    buf is (n_slots, s, width) VMEM; acc the (hot, s, LANES) f32 staging
+    accumulator.  Block j+1's ``make_async_copy`` is started before block
+    j's rows are pooled, so the copy engine runs a block ahead of the
+    gather loop.  Each compacted block stages exactly its own segment of
+    the pre-bucketed index list (slot-per-index via ``pos``), and the
+    reduce over ``hot`` runs at the end — the reference summation order,
+    independent of block arrival order."""
+    n_slots, _, width = buf.shape   # 2 slots, or 1 when one block ships
     nb = nb_ref[0, 0]
+    aligned = rows % LANES == 0 and rb % LANES == 0
 
     def dma(slot, j):
-        return pltpu.make_async_copy(
-            tbl_ref.at[pl.ds(off_ref[0, j], rb), :],
-            buf.at[slot], sem.at[slot])
-
-    @pl.when(nb > 0)
-    def _():
-        dma(0, 0).start()
-
-    def blk_body(j, acc):
-        slot = jax.lax.rem(j, n_slots)
-
-        @pl.when(j + 1 < nb)
-        def _():
-            dma(jax.lax.rem(j + 1, n_slots), j + 1).start()   # overlap
-        dma(slot, j).wait()
-
-        def pos_body(p, acc):
-            loc = sid_ref[0, p] - off_ref[0, j]
-            row = pl.load(buf, (pl.dslice(slot, 1), pl.dslice(loc, 1),
-                                slice(None)))[0, 0]
-            v = row.astype(jnp.float32) * w_ref[0, p]
-            return jax.lax.dynamic_update_slice(acc, v[None, :],
-                                                (pos_ref[0, p], 0))
-
-        return jax.lax.fori_loop(seg0_ref[0, j], seg1_ref[0, j], pos_body,
-                                 acc)
-
-    acc = jax.lax.fori_loop(0, nb, blk_body,
-                            jnp.zeros((l, s), jnp.float32))
-    out_ref[...] = acc.reshape(nt, hot, s).sum(axis=1).astype(out_ref.dtype)
-
-
-def _stream_kernel_vec(sid_ref, inv_ref, w_ref, off_ref, seg0_ref,
-                       seg1_ref, nb_ref, tbl_ref, out_ref, buf, sem, *,
-                       hot: int, rb: int, chunk: int):
-    """Double-buffered streaming, VECTOR pool: each compacted block's
-    segment is walked in ``chunk``-wide steps that gather a whole
-    (chunk, s) row tile from the VMEM slot in one vector gather and weight
-    it under the segment-tail validity mask, so the staging accumulator
-    fills at vector width.  The accumulator is kept in PLANNED order
-    (segments are contiguous, so every chunk store is a contiguous slab);
-    one inverse-permutation gather at the end restores original positions
-    before the reference ``hot`` reduction — staged values are identical
-    to the scalar kernel's slot-per-index buffer, so the output stays
-    bit-exact.  sid/w ride in padded to l + chunk so tail chunk loads
-    never clamp; a chunk overhang past its segment is weighted 0 and
-    overwritten by the owning (later) block's own chunks."""
-    nt, s = out_ref.shape
-    l = nt * hot                    # sid_ref is (1, l + chunk) padded
-    n_slots = buf.shape[0]
-    nb = nb_ref[0, 0]
-
-    def dma(slot, j):
-        return pltpu.make_async_copy(
-            tbl_ref.at[pl.ds(off_ref[0, j], rb), :],
-            buf.at[slot], sem.at[slot])
-
-    @pl.when(nb > 0)
-    def _():
-        dma(0, 0).start()
-
-    sid = sid_ref[...]              # (1, l + chunk)
-    sw = w_ref[...]                 # (1, l + chunk)
-    lane = jax.lax.broadcasted_iota(jnp.int32, (1, chunk), 1)
-
-    def blk_body(j, acc):
-        slot = jax.lax.rem(j, n_slots)
-
-        @pl.when(j + 1 < nb)
-        def _():
-            dma(jax.lax.rem(j + 1, n_slots), j + 1).start()   # overlap
-        dma(slot, j).wait()
-        block = pl.load(buf, (pl.dslice(slot, 1), slice(None),
-                              slice(None)))[0]                # (rb, s)
-        s0, s1 = seg0_ref[0, j], seg1_ref[0, j]
         off = off_ref[0, j]
+        t = off // rows
+        c = off - t * rows
+        if aligned:
+            c = pl.multiple_of(c, LANES)
+        dst = buf.at[slot] if rb == width else \
+            buf.at[slot, :, pl.ds(0, rb)]
+        return pltpu.make_async_copy(tbl_ref.at[t, :, pl.ds(c, rb)], dst,
+                                     sem.at[slot])
 
-        def chunk_body(c, acc):
-            base = s0 + c * chunk
-            ids = jax.lax.dynamic_slice(sid, (0, base), (1, chunk))
-            wc = jax.lax.dynamic_slice(sw, (0, base), (1, chunk))
-            valid = ((base + lane) < s1).astype(jnp.float32)
-            loc = jnp.clip(ids - off, 0, rb - 1).reshape(chunk)
-            rows = jnp.take(block, loc, axis=0).astype(jnp.float32)
-            vals = rows * (wc * valid).reshape(chunk, 1)
-            return jax.lax.dynamic_update_slice(acc, vals, (base, 0))
+    acc[...] = jnp.zeros(acc.shape, acc.dtype)
 
-        return jax.lax.fori_loop(0, pl.cdiv(s1 - s0, chunk), chunk_body,
-                                 acc)
+    @pl.when(nb > 0)
+    def _():
+        dma(0, 0).start()
 
-    acc = jax.lax.fori_loop(0, nb, blk_body,
-                            jnp.zeros((l + chunk, s), jnp.float32))
-    staged = jnp.take(acc, inv_ref[0, :l], axis=0)            # unsort
-    out_ref[...] = staged.reshape(nt, hot, s).sum(axis=1) \
-        .astype(out_ref.dtype)
+    def blk_body(j, carry):
+        slot = jax.lax.rem(j, n_slots)
+
+        @pl.when(j + 1 < nb)
+        def _():
+            dma(jax.lax.rem(j + 1, n_slots), j + 1).start()   # overlap
+        dma(slot, j).wait()
+        off = off_ref[0, j]
+        src = buf.at[slot]
+
+        def pool(p):
+            # overhang positions belong to later blocks: clip into this
+            # block, the owner overwrites their slot afterwards
+            loc = jnp.clip(sid_ref[0, p] - off, 0, rb - 1)
+            _stage_col(src, acc, loc, pos_ref[0, p], w_ref[0, p], hot=hot)
+
+        _walk(seg0_ref[0, j], seg1_ref[0, j], pool, chunk)
+        return carry
+
+    jax.lax.fori_loop(0, nb, blk_body, 0)
+    out_ref[...] = acc[...].sum(axis=0).astype(out_ref.dtype)
 
 
 def _stream_rows_jnp(table_flat, plan: StreamPlan, sw, *, nt: int,
@@ -537,11 +584,9 @@ def _stream_rows_jnp(table_flat, plan: StreamPlan, sw, *, nt: int,
     reduction runs over ``hot`` in the reference order, so the result is
     bit-identical to BOTH kernel pool modes and the jnp oracle in f32.
 
-    This is what ``interpret`` dispatch uses inside jitted multi-device
-    shard_map: this jax version miscompiles interpret-mode ``pallas_call``
-    machinery under compiled SPMD (plain ops are fine, and native Mosaic
-    lowering on TPU is unaffected), so CPU validation of the streamed
-    path runs the schedule as ordinary ops."""
+    This is what ``interpret`` dispatch uses inside the jitted
+    multi-device forward, so CPU validation of the streamed path there
+    runs the schedule as ordinary ops."""
     _, s = table_flat.shape
     tiles, L = plan.sid.shape
 
@@ -562,37 +607,44 @@ def _stream_rows_jnp(table_flat, plan: StreamPlan, sw, *, nt: int,
                               plan.cum, sw).reshape(tiles * nt, s)
 
 
-def _stream_rows(table_flat, gid, w, *, row_tile: int, rb: int,
+def _smem_spec(n: int):
+    """One tile's (1, n) row of a (tiles, 1, n) plan array, in SMEM: the
+    block spans the array's last two dims, as Mosaic's tiling rule asks."""
+    return pl.BlockSpec((None, 1, n), lambda i: (i, 0, 0),
+                        memory_space=pltpu.SMEM)
+
+
+def _stream_rows(tables, gid, w, *, row_tile: int, rb: int,
                  interpret: bool, out_dtype, dma=None,
                  pool_mode: str = "vector", plan: StreamPlan = None,
                  plan_method: str = "auto"):
-    """The streaming core: table_flat (total_rows, s) in HBM, gid (N, hot)
-    int32 pre-clipped flat row ids, w (N, hot) weights -> (N, s) pooled
+    """The streaming core: tables (T, R, s) in HBM, gid (N, hot) int32
+    pre-clipped flat row ids t·R + r, w (N, hot) weights -> (N, s) pooled
     bags.  N is padded to a whole number of row tiles internally (pad rows
     carry weight 0 and pool to zero).
 
     ``dma`` None = the async-copy Pallas kernel on native lowering, the
     pure-jax schedule emulation (:func:`_stream_rows_jnp`) in interpret
     mode; True forces the Pallas kernel (tests validate the DMA pipeline
-    itself on CPU this way — sound standalone, NOT inside compiled
-    multi-device shard_map); False forces the emulation.  ``plan``
+    itself on CPU this way); False forces the emulation.  ``plan``
     consumes a precomputed :class:`StreamPlan` (geometry-checked) instead
     of building one inline; the emulation and both kernel pool modes all
     execute the same plan, so which executor ran never shows in the
     output."""
-    total_rows, s = table_flat.shape
+    t, rows, s = tables.shape
+    total_rows = t * rows
     n, hot = gid.shape
     vector = resolve_pool_mode(pool_mode) == "vector"   # validate up front
     nt, tiles, n_pad, L, nbmax, n_slots = _stream_geometry(
-        total_rows, s, n, hot, row_tile, rb)
+        total_rows, s, n, hot, row_tile, rb, rows)
     if n_pad != n:
         gid = jnp.pad(gid, ((0, n_pad - n), (0, 0)))
         w = jnp.pad(w, ((0, n_pad - n), (0, 0)))
     if plan is None:
         plan = _stream_plan(gid.reshape(tiles, L), rb, total_rows, nbmax,
-                            plan_method)
+                            plan_method, rows)
     else:
-        _check_plan(plan, tiles, L, nbmax, rb, total_rows)
+        _check_plan(plan, tiles, L, nbmax, rb, total_rows, rows)
     # weights are permuted into plan order HERE (an O(L) gather), never
     # inside the plan — a plan built from indices alone stays valid for
     # any miss-mask the cache produces at serving time
@@ -600,98 +652,68 @@ def _stream_rows(table_flat, gid, w, *, row_tile: int, rb: int,
                              plan.pos, axis=-1)
     use_dma = dma if dma is not None else not interpret
     if not use_dma:
-        return _stream_rows_jnp(table_flat, plan, sw, nt=nt, hot=hot,
-                                rb=rb, out_dtype=out_dtype)[:n]
-    row_spec = lambda i: (i, 0)                      # noqa: E731
-    if vector:
-        chunk = _stream_pool_chunk(L, nbmax)
-        # pad the planned id/weight rows by one chunk so segment-tail
-        # chunk loads never clamp backwards (the mask zeroes the overhang)
-        sid_in = jnp.pad(plan.sid, ((0, 0), (0, chunk)))
-        perm_in = plan.inv
-        sw = jnp.pad(sw, ((0, 0), (0, chunk)))
-        l_in = L + chunk
-        kernel = functools.partial(_stream_kernel_vec, hot=hot, rb=rb,
-                                   chunk=chunk)
-    else:
-        sid_in, perm_in, l_in = plan.sid, plan.pos, L
-        kernel = functools.partial(_stream_kernel, hot=hot, rb=rb)
+        return _stream_rows_jnp(tables.reshape(total_rows, s), plan, sw,
+                                nt=nt, hot=hot, rb=rb,
+                                out_dtype=out_dtype)[:n]
+    if not interpret:
+        _check_lane_aligned(rows, rb)
+    chunk = _stream_pool_chunk(L, nbmax) if vector else 1
+    sid, pos = plan.sid, plan.pos
+    if chunk > 1:
+        # the last chunk may overhang the list: padded slots address bag
+        # LANES, which matches no accumulator lane
+        ext = ((0, 0), (0, chunk))
+        sid = jnp.pad(sid, ext)
+        pos = jnp.pad(pos, ext, constant_values=LANES * hot)
+        sw = jnp.pad(sw, ext)
+    lp = sid.shape[1]
+    width = max(rb, LANES)
     out = pl.pallas_call(
-        kernel,
+        functools.partial(_stream_kernel, hot=hot, rb=rb, rows=rows,
+                          chunk=chunk),
         grid=(tiles,),
         in_specs=[
-            pl.BlockSpec((1, l_in), row_spec),       # planned row ids
-            pl.BlockSpec((1, L), row_spec),          # pos (scalar) / inv
-            pl.BlockSpec((1, l_in), row_spec),       # planned weights
-            pl.BlockSpec((1, nbmax), row_spec),      # block DMA start rows
-            pl.BlockSpec((1, nbmax), row_spec),      # segment starts
-            pl.BlockSpec((1, nbmax), row_spec),      # segment ends
-            pl.BlockSpec((1, 1), row_spec),          # compacted block count
-            pl.BlockSpec(memory_space=pltpu.ANY),    # table stays in HBM
+            _smem_spec(lp),                         # planned row ids
+            _smem_spec(lp),                         # staging slot per id
+            _smem_spec(lp),                         # planned weights
+            _smem_spec(nbmax),                      # block start rows
+            _smem_spec(nbmax),                      # segment starts
+            _smem_spec(nbmax),                      # segment ends
+            _smem_spec(1),                          # compacted block count
+            pl.BlockSpec(memory_space=pl.ANY),      # table stays in HBM
         ],
-        out_specs=pl.BlockSpec((nt, s), row_spec),
-        out_shape=jax.ShapeDtypeStruct((n_pad, s), out_dtype),
+        out_specs=pl.BlockSpec((None, s, LANES), lambda i: (i, 0, 0)),
+        out_shape=jax.ShapeDtypeStruct((tiles, s, LANES), out_dtype),
         scratch_shapes=[
-            pltpu.VMEM((n_slots, rb, s), table_flat.dtype),  # double buffer
+            pltpu.VMEM((n_slots, s, width), tables.dtype),  # double buffer
+            pltpu.VMEM((hot, s, LANES), jnp.float32),       # staging
             pltpu.SemaphoreType.DMA((n_slots,)),
         ],
         interpret=interpret,
-    )(sid_in, perm_in, sw, plan.off, plan.seg0, plan.seg1, plan.nblk,
-      table_flat)
-    return out[:n]
+    )(*(a[:, None, :] for a in (sid, pos, sw, plan.off, plan.seg0,
+                                 plan.seg1, plan.nblk)),
+      jnp.swapaxes(tables, 1, 2))
+    return out[:, :, :nt].transpose(0, 2, 1).reshape(n_pad, s)[:n]
 
 
 # ---------------------------------------------------------------------------
-# VMEM-resident kernels (small tables; the pre-streaming fast path)
+# VMEM-resident kernel (small tables; the pre-streaming fast path)
 # ---------------------------------------------------------------------------
 
 
-def _kernel(idx_ref, mask_ref, table_ref, out_ref, *, hot: int):
-    bt = out_ref.shape[0]
-    r = table_ref.shape[0]
+def _resident_kernel(ids_ref, w_ref, tbl_ref, out_ref, acc, *, hot: int,
+                     n: int, chunk: int):
+    """Pool one (table, bag-tile) grid step straight out of the resident
+    (s, R) table block: index p of the tile's (1, ·) SMEM id/weight rows
+    stages into slot p; positions past ``n`` (vector-pool overhang) carry
+    weight 0 into bags no output keeps."""
+    acc[...] = jnp.zeros(acc.shape, acc.dtype)
 
-    def body(i, acc):
-        b, h = i // hot, i % hot
-        row_id = jnp.clip(idx_ref[b, h], 0, r - 1)
-        row = pl.load(table_ref, (pl.dslice(row_id, 1), slice(None)))
-        w = mask_ref[b, h].astype(jnp.float32)
-        return jax.lax.dynamic_update_slice(
-            acc, (row[0].astype(jnp.float32) * w)[None, None, :], (b, h, 0))
+    def pool(p):
+        _stage_col(tbl_ref, acc, ids_ref[0, p], p, w_ref[0, p], hot=hot)
 
-    acc0 = jnp.zeros((bt, hot, table_ref.shape[1]), jnp.float32)
-    acc = jax.lax.fori_loop(0, bt * hot, body, acc0)
-    out_ref[...] = acc.sum(axis=1).astype(out_ref.dtype)
-
-
-def _chunked_gather_pool(tbl, ids, w, bt: int, hot: int):
-    """The vector pool inner loop shared by both resident kernels: walk the
-    flat (bt·hot) index list in POOL_CHUNK-wide steps, gather a whole
-    (chunk, s) row tile per step and weight it, staging slot-per-index
-    into an f32 accumulator that reduces over ``hot`` at the end — the
-    reference summation order, so the output is bit-identical to the
-    scalar walk.  The chunk-tail overhang is padded with id 0 / weight 0
-    (validity folded into the weights) and sliced off before the reduce."""
-    s = tbl.shape[1]
-    l = bt * hot
-    l_pad = -(-l // POOL_CHUNK) * POOL_CHUNK
-    ids = jnp.pad(ids.reshape(l), (0, l_pad - l))
-    w = jnp.pad(w.reshape(l).astype(jnp.float32), (0, l_pad - l))
-    acc = jnp.zeros((l_pad, s), jnp.float32)
-    for base in range(0, l_pad, POOL_CHUNK):
-        idc = jax.lax.slice(ids, (base,), (base + POOL_CHUNK,))
-        wc = jax.lax.slice(w, (base,), (base + POOL_CHUNK,))
-        rows = jnp.take(tbl, idc, axis=0).astype(jnp.float32)
-        acc = jax.lax.dynamic_update_slice(acc, rows * wc[:, None],
-                                           (base, 0))
-    return acc[:l].reshape(bt, hot, s).sum(axis=1)
-
-
-def _kernel_vec(idx_ref, mask_ref, table_ref, out_ref, *, hot: int):
-    bt = out_ref.shape[0]
-    r = table_ref.shape[0]
-    ids = jnp.clip(idx_ref[...], 0, r - 1)
-    out_ref[...] = _chunked_gather_pool(table_ref[...], ids, mask_ref[...],
-                                        bt, hot).astype(out_ref.dtype)
+    _walk(0, n, pool, chunk)
+    out_ref[...] = acc[...].sum(axis=0).astype(out_ref.dtype)
 
 
 def _pad_batch(b: int, bt: int, *arrays):
@@ -706,87 +728,24 @@ def _pad_batch(b: int, bt: int, *arrays):
 
 
 def _stage_tile(tile: int, b: int, hot: int, s: int) -> int:
-    """Clamp a batch/row tile so the (tile, hot, s) f32 staging accumulator
-    every kernel regime carries stays inside STAGE_VMEM_BYTES."""
+    """Clamp a batch/row tile so the (tile, hot, s) f32 staging work every
+    kernel regime carries stays inside STAGE_VMEM_BYTES."""
     return max(1, min(tile, b, STAGE_VMEM_BYTES // max(hot * s * 4, 1)))
 
 
-def embedding_bag(table, idx, mask, *, batch_tile: int = 64,
-                  row_block: int = 0, pool_mode: str = "auto",
-                  interpret: bool = False, dma=None,
-                  plan: StreamPlan = None, plan_method: str = "auto"):
+def embedding_bag(table, idx, mask, **kw):
     """table:(R,S) idx:(B,hot) int32 mask:(B,hot) -> (B,S).
 
-    Partial batch tiles are padded internally (any B works); ``row_block``
-    selects the resident vs streamed regime and ``pool_mode`` the scalar vs
-    vector pooling loop (module docstring).  ``plan`` consumes a
-    precomputed :class:`StreamPlan` (streamed regime only)."""
-    r, s = table.shape
-    b, hot = idx.shape
-    idx = idx.astype(jnp.int32)
-    streamed, rb = resolve_row_block(r, s, jnp.dtype(table.dtype).itemsize,
-                                     row_block)
-    if streamed:
-        return _stream_rows(table, jnp.clip(idx, 0, r - 1), mask,
-                            row_tile=batch_tile, rb=rb, interpret=interpret,
-                            out_dtype=table.dtype, dma=dma,
-                            pool_mode=pool_mode, plan=plan,
-                            plan_method=plan_method)
-    if plan is not None:
-        raise ValueError("plan= only applies to the streamed regime "
-                         "(this call resolved VMEM-resident)")
-    body = _kernel_vec if resolve_pool_mode(pool_mode) == "vector" \
-        else _kernel
-    bt = _stage_tile(batch_tile, b, hot, s)
-    b_pad, idx, mask = _pad_batch(b, bt, idx, mask)
-    out = pl.pallas_call(
-        functools.partial(body, hot=hot),
-        grid=(b_pad // bt,),
-        in_specs=[
-            pl.BlockSpec((bt, hot), lambda i: (i, 0)),
-            pl.BlockSpec((bt, hot), lambda i: (i, 0)),
-            pl.BlockSpec((r, s), lambda i: (0, 0)),  # table resident
-        ],
-        out_specs=pl.BlockSpec((bt, s), lambda i: (i, 0)),
-        out_shape=jax.ShapeDtypeStruct((b_pad, s), table.dtype),
-        interpret=interpret,
-    )(idx, mask, table)
-    return out[:b]
+    The one-table stack of :func:`embedding_bag_stacked` (same keywords:
+    ``batch_tile``, ``row_block``, ``pool_mode``, ``interpret``, ``dma``,
+    ``plan``, ``plan_method``)."""
+    return embedding_bag_stacked(table[None], idx[:, None], mask[:, None],
+                                 **kw)[:, 0]
 
 
 # ---------------------------------------------------------------------------
 # stacked-table form: the whole sparse arsenal in one call
 # ---------------------------------------------------------------------------
-
-
-def _stacked_kernel(idx_ref, mask_ref, table_ref, out_ref, *, hot: int):
-    # blocks: idx/mask (bt, 1, hot), table (1, R, s), out (bt, 1, s)
-    bt = out_ref.shape[0]
-    r, s = table_ref.shape[1], table_ref.shape[2]
-
-    def body(i, acc):
-        b, h = i // hot, i % hot
-        row_id = jnp.clip(idx_ref[b, 0, h], 0, r - 1)
-        row = pl.load(table_ref,
-                      (pl.dslice(0, 1), pl.dslice(row_id, 1), slice(None)))
-        w = mask_ref[b, 0, h].astype(jnp.float32)
-        return jax.lax.dynamic_update_slice(
-            acc, (row[0, 0].astype(jnp.float32) * w)[None, None, :],
-            (b, h, 0))
-
-    acc0 = jnp.zeros((bt, hot, s), jnp.float32)
-    acc = jax.lax.fori_loop(0, bt * hot, body, acc0)
-    out_ref[...] = acc.sum(axis=1)[:, None, :].astype(out_ref.dtype)
-
-
-def _stacked_kernel_vec(idx_ref, mask_ref, table_ref, out_ref, *,
-                        hot: int):
-    bt = out_ref.shape[0]
-    r = table_ref.shape[1]
-    ids = jnp.clip(idx_ref[:, 0, :], 0, r - 1)
-    pooled = _chunked_gather_pool(table_ref[0], ids, mask_ref[:, 0, :],
-                                  bt, hot)
-    out_ref[...] = pooled[:, None, :].astype(out_ref.dtype)
 
 
 def _stacked_gid(t: int, r: int, idx):
@@ -806,15 +765,13 @@ def stacked_stream_plan(t: int, r: int, s: int, itemsize: int, idx, *,
     via ``embedding_bag_stacked(..., plan=...)``."""
     b, t2, hot = idx.shape
     assert t == t2, (t, t2)
-    streamed, _ = resolve_row_block(r, s, itemsize, row_block)
+    streamed, rb = resolve_row_block(r, s, itemsize, row_block)
     if not streamed:
         return None
-    rb = min(row_block, t * r) if row_block > 0 \
-        else auto_row_block(t * r, s, itemsize)
     gid = _stacked_gid(t, r, idx)
     return build_stream_plan(t * r, s, gid.reshape(b * t, hot),
                              row_tile=batch_tile, rb=rb,
-                             plan_method=plan_method)
+                             plan_method=plan_method, rows=r)
 
 
 def embedding_bag_stacked(tables, idx, mask, *, batch_tile: int = 64,
@@ -830,27 +787,21 @@ def embedding_bag_stacked(tables, idx, mask, *, batch_tile: int = 64,
     tiles, and the (B,T,hot,s) broadcast-gather intermediate the pure-jnp
     reference materializes never exists.  Streamed regime (``row_block``):
     the stack is addressed as one flat (T·R, s) row space (global row id =
-    t·R + idx — a free reshape) and pooled through the double-buffered DMA
-    core, so tables of production size run at streaming bandwidth instead
-    of failing the residency assumption.  ``pool_mode`` picks the scalar
-    walk or the chunked vector gather in BOTH regimes; ``plan`` consumes a
-    :func:`stacked_stream_plan` built off the critical path.  Partial
-    batch tiles are padded internally (any B works)."""
+    t·R + idx) and pooled through the double-buffered DMA core in per-
+    table blocks, so tables of production size run at streaming bandwidth
+    instead of failing the residency assumption.  ``pool_mode`` picks the
+    scalar walk or the unrolled vector walk in BOTH regimes; ``plan``
+    consumes a :func:`stacked_stream_plan` built off the critical path.
+    Partial batch tiles are padded internally (any B works)."""
     t, r, s = tables.shape
     b, t2, hot = idx.shape
     assert t == t2, (t, t2)
     idx = idx.astype(jnp.int32)
     item = jnp.dtype(tables.dtype).itemsize
-    # residency is decided per TABLE block (what the resident kernel keeps
-    # live), but the streamed regime addresses the flat (T·R, s) space, so
-    # an explicit block height clips against t*r, not r
-    streamed, _ = resolve_row_block(r, s, item, row_block)
+    streamed, rb = resolve_row_block(r, s, item, row_block)
     if streamed:
-        rb = min(row_block, t * r) if row_block > 0 \
-            else auto_row_block(t * r, s, item)
         gid = _stacked_gid(t, r, idx)
-        out = _stream_rows(tables.reshape(t * r, s),
-                           gid.reshape(b * t, hot),
+        out = _stream_rows(tables, gid.reshape(b * t, hot),
                            mask.reshape(b * t, hot),
                            row_tile=batch_tile, rb=rb,
                            interpret=interpret, out_dtype=tables.dtype,
@@ -860,22 +811,39 @@ def embedding_bag_stacked(tables, idx, mask, *, batch_tile: int = 64,
     if plan is not None:
         raise ValueError("plan= only applies to the streamed regime "
                          "(this call resolved VMEM-resident)")
-    body = _stacked_kernel_vec if resolve_pool_mode(pool_mode) == "vector" \
-        else _stacked_kernel
-    bt = _stage_tile(batch_tile, b, hot, s)
+    chunk = POOL_CHUNK if resolve_pool_mode(pool_mode) == "vector" else 1
+    bt = min(_stage_tile(batch_tile, b, hot, s), LANES)
     b_pad, idx, mask = _pad_batch(b, bt, idx, mask)
+    nbt = b_pad // bt
+    n = bt * hot
+    lp = -(-n // chunk) * chunk
+
+    def per_tile(a):   # (B_pad, T, hot) -> (T, nbt, 1, lp) SMEM rows
+        a = a.transpose(1, 0, 2).reshape(t, nbt, n)
+        return jnp.pad(a, ((0, 0), (0, 0), (0, lp - n)))[:, :, None, :]
+
+    # a resident block is small: pad its lanes to whole tiles so every
+    # aligned tile load stays inside the block
+    rp = max(LANES, -(-r // LANES) * LANES)
+    tt = jnp.pad(jnp.swapaxes(tables, 1, 2), ((0, 0), (0, 0), (0, rp - r)))
+    smem = pl.BlockSpec((None, None, 1, lp), lambda ti, bi: (ti, bi, 0, 0),
+                        memory_space=pltpu.SMEM)
     out = pl.pallas_call(
-        functools.partial(body, hot=hot),
-        grid=(t, b_pad // bt),
+        functools.partial(_resident_kernel, hot=hot, n=n, chunk=chunk),
+        grid=(t, nbt),
         in_specs=[
-            pl.BlockSpec((bt, 1, hot), lambda ti, bi: (bi, ti, 0)),
-            pl.BlockSpec((bt, 1, hot), lambda ti, bi: (bi, ti, 0)),
-            pl.BlockSpec((1, r, s), lambda ti, bi: (ti, 0, 0)),  # resident
+            smem,                                          # ids
+            smem,                                          # weights
+            pl.BlockSpec((None, s, rp), lambda ti, bi: (ti, 0, 0)),
         ],
-        out_specs=pl.BlockSpec((bt, 1, s), lambda ti, bi: (bi, ti, 0)),
-        out_shape=jax.ShapeDtypeStruct((b_pad, t, s), tables.dtype),
+        out_specs=pl.BlockSpec((None, None, s, LANES),
+                               lambda ti, bi: (ti, bi, 0, 0)),
+        out_shape=jax.ShapeDtypeStruct((t, nbt, s, LANES), tables.dtype),
+        scratch_shapes=[pltpu.VMEM((hot, s, LANES), jnp.float32)],
         interpret=interpret,
-    )(idx, mask, tables)
+    )(per_tile(jnp.clip(idx, 0, r - 1)),
+      per_tile(mask.astype(jnp.float32)), tt)
+    out = out[..., :bt].transpose(1, 3, 0, 2).reshape(b_pad, t, s)
     return out[:b]
 
 
@@ -895,23 +863,20 @@ def embedding_bag_rows(tables, tid, idx, mask, *, row_tile: int = 64,
     own table.  Runs on the same streaming core — global row id = tid·R +
     idx flattens the stack into one row space, so a small packed set
     (≤ P·cap rows) DMAs only the row blocks it actually touches even when
-    the stack is production-size.  ``row_block`` 0/auto streams the whole
-    stack as one block when it fits the VMEM budget (the resident
-    equivalent — a single scratch slot, no partner buffer) and falls back
-    to streamed blocks otherwise; ``pool_mode`` picks the pooling loop as
-    everywhere else.  (No ``plan=``: the packed row set is data-dependent
-    per step, so there is nothing to precompute.)"""
+    the stack is production-size.  ``row_block`` 0/auto streams each table
+    as one block when it fits the VMEM budget (the resident equivalent)
+    and falls back to streamed blocks otherwise; ``pool_mode`` picks the
+    pooling loop as everywhere else.  (No ``plan=``: the packed row set is
+    data-dependent per step, so there is nothing to precompute.)"""
     t, r, s = tables.shape
-    n, hot = idx.shape
-    total = t * r
     # one resolver with the other entry points: -1 raises past the VMEM
-    # budget, 0 streams the whole stack as a single block when it fits
-    # (the resident equivalent), anything else is validated identically
-    _, rb = resolve_row_block(total, s, jnp.dtype(tables.dtype).itemsize,
+    # budget, 0 streams each table as a single block when it fits (the
+    # resident equivalent), anything else is validated identically
+    _, rb = resolve_row_block(r, s, jnp.dtype(tables.dtype).itemsize,
                               row_block)
     gid = (tid.astype(jnp.int32)[:, None] * r +
            jnp.clip(idx.astype(jnp.int32), 0, r - 1))
-    return _stream_rows(tables.reshape(total, s), gid, mask,
+    return _stream_rows(tables, gid, mask,
                         row_tile=row_tile, rb=rb, interpret=interpret,
                         out_dtype=tables.dtype, dma=dma,
                         pool_mode=pool_mode, plan_method=plan_method)

@@ -22,12 +22,6 @@ from jax.experimental.pallas import tpu as pltpu
 NEG_INF = -1e30
 
 
-def _compiler_params_kw() -> dict:
-    from repro import compat
-    return compat.compiler_params_kw(
-        ("parallel", "parallel", "parallel", "arbitrary"))
-
-
 def _kernel(q_ref, k_ref, v_ref, out_ref, m_scr, l_scr, acc_scr, *,
             scale: float, softcap: float, window: int, causal: bool,
             cq: int, ck: int, n_k: int):
@@ -115,6 +109,7 @@ def flash_attention_pallas(q, k, v, *, causal: bool = True, window: int = 0,
             pltpu.VMEM((cq, hd), jnp.float32),
         ],
         interpret=interpret,
-        **_compiler_params_kw(),
+        compiler_params=pltpu.CompilerParams(dimension_semantics=(
+            "parallel", "parallel", "parallel", "arbitrary")),
     )(q, k, v)
     return out

@@ -23,14 +23,14 @@ def embedding_bag_ref(table, idx, mask):
 
 def embedding_bag_stacked_ref(tables, idx, mask):
     """tables:(T,R,S) idx/mask:(B,T,hot) -> (B,T,S) per-table masked sums.
-    Materializes the (B,T,hot,S) gather the Pallas kernel avoids."""
-    gathered = jnp.take_along_axis(
-        tables[None, :, :, :],
-        jnp.clip(idx[..., None].astype(jnp.int32), 0,
-                 tables.shape[1] - 1),
-        axis=2,
-    )
-    return jnp.sum(gathered * mask[..., None].astype(gathered.dtype), axis=2)
+    Materializes each table's (B,hot,S) gather the Pallas kernel avoids.
+    One table at a time: on the TPU the stack lives rows-minor, and a
+    gather over the whole stack would first copy all of it into a padded
+    rows-major layout (more than a chip holds at Criteo-Kaggle size)."""
+    per_table = jax.lax.map(
+        lambda a: embedding_bag_ref(*a),
+        (tables, idx.astype(jnp.int32).swapaxes(0, 1), mask.swapaxes(0, 1)))
+    return per_table.swapaxes(0, 1)
 
 
 def embedding_bag_rows_ref(tables, tid, idx, mask):

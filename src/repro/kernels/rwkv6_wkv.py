@@ -26,11 +26,6 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-def _compiler_params_kw() -> dict:
-    from repro import compat
-    return compat.compiler_params_kw(("parallel", "parallel", "arbitrary"))
-
-
 def _kernel(r_ref, k_ref, v_ref, w_ref, u_ref, s0_ref, out_ref, sout_ref,
             state, *, n_chunks: int):
     nc = pl.program_id(2)
@@ -102,6 +97,7 @@ def wkv_chunked_pallas(r, k, v, logw, u, state0, *, chunk: int = 64,
                    jax.ShapeDtypeStruct((b, h, kk, vv), jnp.float32)],
         scratch_shapes=[pltpu.VMEM((kk, vv), jnp.float32)],
         interpret=interpret,
-        **_compiler_params_kw(),
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("parallel", "parallel", "arbitrary")),
     )(r, k, v, logw, u, state0)
     return out, sout

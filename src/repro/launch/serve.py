@@ -1,5 +1,5 @@
 """Production serving driver: DLRM CTR serving with the BLS pipeline (the
-paper's deployment) or batched LM decode, on whatever mesh is available.
+paper's deployment) or batched LM decode, on a mesh over every local device.
 
 Usage:
   PYTHONPATH=src python -m repro.launch.serve --arch dlrm-kaggle --smoke \
@@ -15,8 +15,10 @@ import numpy as np
 
 from repro.configs import base as cb
 from repro.data import synthetic as S
+from repro.launch.mesh import make_host_mesh
 from repro.models import api, dlrm as D
 from repro.serving.engine import DLRMEngine, LMEngine
+from repro.sharding import partition
 
 
 def main():
@@ -34,15 +36,24 @@ def main():
     cfg = spec.smoke() if args.smoke else spec.config
 
     if args.arch.startswith("dlrm"):
-        params = D.init_dlrm(jax.random.PRNGKey(0), cfg, n_shards=1)
+        # table-parallel over every local device: the engine serves the BLS
+        # pipeline through the model-axis exchange, as deployed
+        n_model = len(jax.devices())
+        while args.batch_size % (args.microbatches * n_model):
+            n_model //= 2
+        mesh = make_host_mesh(model=n_model)
+        params = D.init_dlrm(jax.random.PRNGKey(0), cfg, n_shards=n_model,
+                             mesh=mesh)
+        t_pad = D.padded_tables(cfg, n_model)
         eng = DLRMEngine(params, cfg, batch_size=args.batch_size,
                          bound=args.bound, microbatches=args.microbatches)
-        for i in range(args.batches):
-            b = S.make_batch(cfg, args.batch_size, mode="hetero", seed=3,
-                             step=i)
-            for j in range(args.batch_size):
-                eng.submit(b.dense[j], b.idx[j], b.mask[j])
-        eng.flush()
+        with partition.axis_rules(mesh):
+            for i in range(args.batches):
+                b = S.make_batch(cfg, args.batch_size, mode="hetero", seed=3,
+                                 step=i, t_pad=t_pad)
+                for j in range(args.batch_size):
+                    eng.submit(b.dense[j], b.idx[j], b.mask[j])
+            eng.flush()
         print(f"served {eng.stats.requests} requests @ "
               f"{eng.stats.throughput_rps:,.0f} req/s "
               f"(bound={args.bound}, mb={args.microbatches})")

@@ -24,7 +24,6 @@ import jax
 import jax.numpy as jnp
 from jax.sharding import PartitionSpec as P
 
-from repro import compat
 from repro.configs.base import DLRMConfig
 from repro.core import alltoallv as a2a_mod
 from repro.core import bls as bls_mod
@@ -43,10 +42,22 @@ def padded_tables(cfg: DLRMConfig, n_shards: int) -> int:
     return ((t + n_shards - 1) // n_shards) * n_shards
 
 
-def init_dlrm(key, cfg: DLRMConfig, n_shards: int = 16):
-    kt, kb, ktop = jax.random.split(key, 3)
+def padded_rows(cfg: DLRMConfig) -> int:
+    """Stack height: the largest table rounded up to whole 128-row lane
+    tiles.  On the TPU a row is a lane column of its table, so the padding
+    costs no memory, and the streamed kernel can DMA lane-aligned blocks
+    (kernels/embedding_bag.py)."""
+    lanes = 128
+    return -(-max(cfg.table_sizes) // lanes) * lanes
+
+
+def init_dlrm(key, cfg: DLRMConfig, n_shards: int = 16, mesh=None):
+    """Random DLRM params, built on the device under jit (no host copy of
+    the stack, no second copy while scaling).  With ``mesh`` the tables
+    come out sharded over its ``model`` axis (each device builds only its
+    own shard) and the MLPs replicated."""
     t_pad = padded_tables(cfg, n_shards)
-    r_max = max(cfg.table_sizes)
+    r_max = padded_rows(cfg)
     dt = jnp.dtype(cfg.dtype)
 
     def mlp_params(key, dims):
@@ -54,21 +65,32 @@ def init_dlrm(key, cfg: DLRMConfig, n_shards: int = 16):
         return [L.init_dense(ks[i], dims[i], dims[i + 1], cfg.dtype,
                              bias=True) for i in range(len(dims) - 1)]
 
-    # N.B. a (T_pad, R_max, s) stack; rows beyond a table's true size are
-    # never indexed (synthetic data clips indices per true table size).
-    tables = L.truncated_normal(kt, (t_pad, r_max, cfg.embed_dim),
-                                1.0 / cfg.embed_dim, dt)
-    bot_dims = (cfg.n_dense_features, *cfg.bottom_mlp)
-    n_feat = cfg.n_tables + 1
-    n_inter = n_feat * (n_feat - 1) // 2 if cfg.arch_interaction_op == "dot" \
-        else n_feat * cfg.embed_dim
-    top_in = n_inter + cfg.embed_dim
-    top_dims = (top_in, *cfg.top_mlp)
-    return {
-        "tables": tables,
-        "bot": mlp_params(kb, bot_dims),
-        "top": mlp_params(ktop, top_dims),
-    }
+    def build(key):
+        kt, kb, ktop = jax.random.split(key, 3)
+        # N.B. a (T_pad, R_max, s) stack; rows beyond a table's true size
+        # are never indexed (synthetic data clips indices per true size).
+        tables = L.truncated_normal(kt, (t_pad, r_max, cfg.embed_dim),
+                                    1.0 / cfg.embed_dim, dt)
+        bot_dims = (cfg.n_dense_features, *cfg.bottom_mlp)
+        n_feat = cfg.n_tables + 1
+        n_inter = n_feat * (n_feat - 1) // 2 \
+            if cfg.arch_interaction_op == "dot" else n_feat * cfg.embed_dim
+        top_in = n_inter + cfg.embed_dim
+        top_dims = (top_in, *cfg.top_mlp)
+        return {
+            "tables": tables,
+            "bot": mlp_params(kb, bot_dims),
+            "top": mlp_params(ktop, top_dims),
+        }
+
+    if mesh is None:
+        return jax.jit(build)(key)
+    from jax.sharding import NamedSharding
+    shapes = jax.eval_shape(build, key)
+    rep = NamedSharding(mesh, P())
+    out = jax.tree.map(lambda _: rep, shapes)
+    out["tables"] = NamedSharding(mesh, P("model", None, None))
+    return jax.jit(build, out_shardings=out)(key)
 
 
 def dlrm_specs(cfg: DLRMConfig):
@@ -111,24 +133,24 @@ def apply_emb(tables, idx, mask, backend: str = "ref",
     """Embedding bags.  tables:(T,R,s) idx:(B,T,hot) mask:(B,T,hot)
     -> (B,T,s).  The paper's dominant stage (its Fig. 5 flame graph).
 
-    backend 'ref' is the pure-jnp contraction (materializes the
-    (B,T,hot,s) broadcast gather); 'pallas'/'interpret' dispatch to the
+    backend 'ref' is the pure-jnp contraction (materializes each
+    table's (B,hot,s) gather); 'pallas' dispatches to the compiled
     stacked-table kernel in kernels/embedding_bag.py, which streams rows
-    through VMEM and never builds that intermediate.  ``row_block``
+    through VMEM and never builds that intermediate, and 'interpret' runs
+    that kernel body through the Pallas interpreter.  ``row_block``
     (cfg.row_block) picks the kernel regime: 0 auto — VMEM-resident table
     blocks when they fit, double-buffered DMA row streaming otherwise;
-    ``pool_mode`` (cfg.pool_mode) the scalar walk vs the chunked vector
-    gather (DESIGN.md §1).  ``plan`` consumes a precomputed StreamPlan
+    ``pool_mode`` (cfg.pool_mode) the scalar walk vs the unrolled
+    vector walk (DESIGN.md §1).  ``plan`` consumes a precomputed StreamPlan
     (kernels.embedding_bag.stacked_stream_plan / build_forward_plans) so
     the index-bucketing sort sits off the critical path; the jnp reference
     has no plan to consume, so passing one with backend 'ref' raises."""
     backend = resolve_sparse_backend(backend)
     if backend != "ref":
-        # ops owns tile choice + interpret-off-TPU; 'pallas' degrades to
-        # interpret mode away from TPU rather than failing at lowering
         from repro.kernels.ops import embedding_bag_stacked_op
         return embedding_bag_stacked_op(tables, idx.astype(jnp.int32),
-                                        mask, row_block=row_block,
+                                        mask, impl=backend,
+                                        row_block=row_block,
                                         pool_mode=pool_mode, plan=plan)
     if plan is not None:
         raise ValueError("apply_emb: a precomputed stream plan only "
@@ -183,7 +205,7 @@ def apply_emb_rows(tables, tid, idx, mask, backend: str = "ref",
         from repro.kernels.ops import embedding_bag_rows_op
         return embedding_bag_rows_op(tables, tid.astype(jnp.int32),
                                      idx.astype(jnp.int32), mask,
-                                     row_block=row_block,
+                                     impl=backend, row_block=row_block,
                                      pool_mode=pool_mode)
     from repro.kernels.ref import embedding_bag_rows_ref
     return embedding_bag_rows_ref(tables, tid, idx, mask)
@@ -302,9 +324,13 @@ def forward_local(params, cfg: DLRMConfig, dense, idx, mask):
     """Single-device reference forward (oracle for the distributed path)."""
     t = cfg.n_tables
     z0 = apply_mlp(params["bot"], dense)                       # (B, s)
-    emb = apply_emb(params["tables"][:t], idx[:, :t], mask[:, :t],
+    # pool every table the batch carries (T, or T_pad with empty padding
+    # bags) and drop the padding after: slicing a padded stack down to T
+    # would copy all of it
+    n = idx.shape[1]
+    emb = apply_emb(params["tables"][:n], idx, mask,
                     backend=cfg.sparse_backend, row_block=cfg.row_block,
-                    pool_mode=cfg.pool_mode)
+                    pool_mode=cfg.pool_mode)[:, :t]
     z = jnp.concatenate([z0[:, None, :], emb], axis=1)         # (B, T+1, s)
     inter = dot_interaction(z)
     top_in = jnp.concatenate([z0, inter.astype(z0.dtype)], axis=-1)
@@ -1157,7 +1183,7 @@ def forward_distributed(params, cfg: DLRMConfig, dense, idx, mask, *,
         # per-destination corrupt-source flags: (P_dst · P_src,) global,
         # reshaped host-side
         out_specs = out_specs + (P("model"),)
-    out, *rest_out = compat.shard_map(
+    out, *rest_out = jax.shard_map(
         shard_fn, mesh=mesh,
         in_specs=tuple(in_specs),
         out_specs=out_specs,
@@ -1274,7 +1300,7 @@ def build_forward_plans(params, cfg: DLRMConfig, idx, *,
     b_mb = idx.shape[0] // (n_data * mb)
     plan_struct = jax.eval_shape(per_mb, jax.ShapeDtypeStruct(
         (b_mb, t_loc, idx.shape[2]), jnp.int32))
-    return compat.shard_map(
+    return jax.shard_map(
         plan_fn, mesh=mesh, in_specs=(sparse_spec,),
         out_specs=jax.tree.map(lambda _: out_spec, plan_struct),
         check_vma=False,
@@ -1294,7 +1320,7 @@ def bce_loss(logits, labels):
 
 def table_stats(cfg: DLRMConfig, n_shards: int = 16) -> dict:
     t_pad = padded_tables(cfg, n_shards)
-    r_max = max(cfg.table_sizes)
+    r_max = padded_rows(cfg)
     real = sum(cfg.table_sizes) * cfg.embed_dim
     padded = t_pad * r_max * cfg.embed_dim
     return {"t_pad": t_pad, "r_max": r_max,

@@ -23,7 +23,6 @@ import jax
 import jax.numpy as jnp
 from jax.sharding import PartitionSpec as P
 
-from repro import compat
 from repro.configs.base import ModelConfig, MoEConfig
 from repro.models import layers as L
 from repro.sharding import partition
@@ -182,7 +181,7 @@ def moe_gather(params, cfg: ModelConfig, x):
         return out.reshape(xs.shape), aux
 
     batch_spec = P(data_ax if data_ax else None, None, None)
-    out, aux = compat.shard_map(
+    out, aux = jax.shard_map(
         shard_fn, mesh=mesh,
         in_specs=(P(), P("model", None, None), P("model", None, None),
                   P("model", None, None), batch_spec),
@@ -262,7 +261,7 @@ def moe_a2a(params, cfg: ModelConfig, x, *, axis: str = "model"):
         return out.reshape(xs.shape), aux
 
     batch_spec = P(data_ax if data_ax else None, axis, None)
-    out, aux = compat.shard_map(
+    out, aux = jax.shard_map(
         shard_fn, mesh=mesh,
         in_specs=(P(), P(axis, None, None), P(axis, None, None),
                   P(axis, None, None), batch_spec),

@@ -31,11 +31,10 @@ def pick_mesh_shape(n_devices: int, model: int = 0) -> tuple:
 
 
 def make_mesh_from(devices, model: int = 0) -> Mesh:
-    from repro import compat
     shape = pick_mesh_shape(len(devices), model)
     import numpy as np
     arr = np.asarray(devices)[:shape[0] * shape[1]].reshape(shape)
-    return compat.mesh_from(arr, ("data", "model"))
+    return Mesh(arr, ("data", "model"))
 
 
 def reshard(tree, shardings):

@@ -203,7 +203,7 @@ class DLRMEngine:
         # table blocks when they fit VMEM, DMA row streaming otherwise
         self.row_block = row_block if row_block is not None \
             else cfg.row_block
-        # pooling loop: chunked vector gather vs scalar walk (DESIGN.md §1)
+        # pooling loop: unrolled vector walk vs scalar walk (DESIGN.md §1)
         self.pool_mode = pool_mode if pool_mode is not None \
             else cfg.pool_mode
         self.plan_pipeline = plan_pipeline

@@ -79,7 +79,7 @@ def shard_fn(rows, dest):
 
 rows = jnp.arange(8 * 32 * 4.0).reshape(8 * 32, 4)
 dest = jnp.asarray(np.random.default_rng(0).integers(0, 8, 8 * 32))
-total = jax.jit(compat.shard_map(shard_fn, mesh=mesh,
+total = jax.jit(jax.shard_map(shard_fn, mesh=mesh,
     in_specs=(P("model"), P("model")), out_specs=P("model"),
     check_vma=False))(rows, dest)
 assert jnp.allclose(total[0], rows.sum()), (float(total[0]), float(rows.sum()))
@@ -167,7 +167,7 @@ def make(bound):
         out, _ = bls_pipeline(stage_a, coll, stage_b, xs, bound)
         return out
 
-    return jax.jit(compat.shard_map(shard_fn, mesh=mesh,
+    return jax.jit(jax.shard_map(shard_fn, mesh=mesh,
         in_specs=(P(), P("model", None, None), P("model", None, None),
                   P("model", None, None), P(None, "model", None)),
         out_specs=P(None, "model", None), check_vma=False))

@@ -63,7 +63,7 @@ def run(bound):
             return reference_loop(a, c, b, x)
         out, _ = bls_pipeline(a, c, b, x, bound)
         return out
-    return jax.jit(compat.shard_map(shard_fn, mesh=mesh,
+    return jax.jit(jax.shard_map(shard_fn, mesh=mesh,
         in_specs=P(None, "data", "model", None),
         out_specs=P(None, ("data", "model")), check_vma=False))
 x = jax.random.normal(jax.random.PRNGKey(0), (5, 8, 4, 6))

@@ -64,7 +64,8 @@ class TestStreamedStackedParity:
     def test_bit_exact_vs_ref(self, r, rb):
         tbl, idx, mask = _case(2, r, 16, 16, 4, seed=r, boundary_rb=rb)
         want = ref.embedding_bag_stacked_ref(tbl, idx, mask)
-        got = ops.embedding_bag_stacked_op(tbl, idx, mask, row_block=rb)
+        got = ops.embedding_bag_stacked_op(tbl, idx, mask, row_block=rb,
+                                           impl="interpret")
         assert got.shape == want.shape and got.dtype == want.dtype
         assert np.array_equal(np.asarray(got), np.asarray(want))
 
@@ -96,16 +97,18 @@ class TestStreamedStackedParity:
         for row_block in (0, 128):
             got = ops.embedding_bag_stacked_op(tbl, idx, mask,
                                                batch_tile=16,
-                                               row_block=row_block)
+                                               row_block=row_block,
+                                               impl="interpret")
             assert np.array_equal(np.asarray(got), np.asarray(want)), \
                 row_block
 
     def test_streamed_matches_resident_bitwise(self):
         tbl, idx, mask = _case(2, 2000, 16, 24, 4, seed=3, boundary_rb=256)
         resident = ops.embedding_bag_stacked_op(tbl, idx, mask,
-                                                row_block=-1)
+                                                row_block=-1, impl="interpret")
         streamed = ops.embedding_bag_stacked_op(tbl, idx, mask,
-                                                row_block=256)
+                                                row_block=256,
+                                                impl="interpret")
         assert np.array_equal(np.asarray(resident), np.asarray(streamed))
 
     def test_single_table_entry_point(self):
@@ -113,7 +116,8 @@ class TestStreamedStackedParity:
         want = ref.embedding_bag_ref(tbl[0], idx[:, 0], mask[:, 0])
         for row_block in (0, 192):
             got = ops.embedding_bag_op(tbl[0], idx[:, 0], mask[:, 0],
-                                       batch_tile=16, row_block=row_block)
+                                       batch_tile=16, row_block=row_block,
+                                       impl="interpret")
             assert np.array_equal(np.asarray(got), np.asarray(want)), \
                 row_block
 
@@ -154,11 +158,12 @@ class TestRowBlockPolicy:
                                   interpret=True)
 
     def test_explicit_block_clips_to_flat_stack_space(self):
-        # the stacked streamed regime addresses (T*R, s): a forced block
-        # height past one table's R must not be silently clipped to R
+        # the stacked streamed regime streams per-table blocks: a forced
+        # block height past one table's R clips to R (one block per table)
         tbl, idx, mask = _case(4, 1000, 8, 8, 2, seed=9)
         want = ref.embedding_bag_stacked_ref(tbl, idx, mask)
-        got = ops.embedding_bag_stacked_op(tbl, idx, mask, row_block=2500)
+        got = ops.embedding_bag_stacked_op(tbl, idx, mask, row_block=2500,
+                                           impl="interpret")
         assert np.array_equal(np.asarray(got), np.asarray(want))
 
     def test_stage_tile_bounds_the_staging_accumulator(self):
@@ -171,7 +176,7 @@ class TestRowBlockPolicy:
         # enough that batch_tile=64 would blow the budget)
         tbl, idx, mask = _case(1, 60, 128, 20, 256, seed=13)
         want = ref.embedding_bag_stacked_ref(tbl, idx, mask)
-        got = ops.embedding_bag_stacked_op(tbl, idx, mask)
+        got = ops.embedding_bag_stacked_op(tbl, idx, mask, impl="interpret")
         assert np.array_equal(np.asarray(got), np.asarray(want))
 
 
@@ -197,7 +202,7 @@ class TestRowsKernel:
             .astype(jnp.float32)
         want = ref.embedding_bag_rows_ref(tbl, tid, idx, mask)
         got = ops.embedding_bag_rows_op(tbl, tid, idx, mask, row_tile=16,
-                                        row_block=rb)
+                                        row_block=rb, impl="interpret")
         assert np.array_equal(np.asarray(got), np.asarray(want))
 
     def test_oob_ids_clip_like_ref(self):
@@ -206,7 +211,8 @@ class TestRowsKernel:
         idx = jnp.asarray([[0, 49], [99, -3], [7, 50]], jnp.int32)
         mask = jnp.ones((3, 2), jnp.float32)
         want = ref.embedding_bag_rows_ref(tbl, tid, idx, mask)
-        got = ops.embedding_bag_rows_op(tbl, tid, idx, mask, row_block=16)
+        got = ops.embedding_bag_rows_op(tbl, tid, idx, mask, row_block=16,
+                                        impl="interpret")
         assert np.array_equal(np.asarray(got), np.asarray(want))
 
     def test_dead_rows_pool_to_exact_zero(self):
@@ -215,7 +221,8 @@ class TestRowsKernel:
         tid = jnp.zeros((8,), jnp.int32)
         idx = jnp.zeros((8, 4), jnp.int32)
         mask = jnp.zeros((8, 4), jnp.float32)
-        got = ops.embedding_bag_rows_op(tbl, tid, idx, mask, row_block=64)
+        got = ops.embedding_bag_rows_op(tbl, tid, idx, mask, row_block=64,
+                                        impl="interpret")
         assert float(jnp.max(jnp.abs(got))) == 0.0
 
 
@@ -233,9 +240,9 @@ class TestVectorPool:
         tbl, idx, mask = _case(2, 500, 16, 37, hot, seed=hot)
         want = ref.embedding_bag_stacked_ref(tbl, idx, mask)
         sc = ops.embedding_bag_stacked_op(tbl, idx, mask, batch_tile=16,
-                                          pool_mode="scalar")
+                                          pool_mode="scalar", impl="interpret")
         ve = ops.embedding_bag_stacked_op(tbl, idx, mask, batch_tile=16,
-                                          pool_mode="vector")
+                                          pool_mode="vector", impl="interpret")
         assert np.array_equal(np.asarray(sc), np.asarray(want))
         assert np.array_equal(np.asarray(ve), np.asarray(want))
 
@@ -262,7 +269,7 @@ class TestVectorPool:
         for row_block in (0, 128):
             got = ops.embedding_bag_op(tbl[0], idx[:, 0], mask[:, 0],
                                        batch_tile=16, row_block=row_block,
-                                       pool_mode="vector")
+                                       pool_mode="vector", impl="interpret")
             assert np.array_equal(np.asarray(got), np.asarray(want)), \
                 row_block
 
@@ -275,7 +282,8 @@ class TestVectorPool:
             .astype(jnp.float32)
         want = ref.embedding_bag_rows_ref(tbl, tid, idx, mask)
         got = ops.embedding_bag_rows_op(tbl, tid, idx, mask, row_tile=16,
-                                        row_block=512, pool_mode="vector")
+                                        row_block=512, pool_mode="vector",
+                                        impl="interpret")
         assert np.array_equal(np.asarray(got), np.asarray(want))
         # and through the real DMA pipeline
         got_dma = eb.embedding_bag_rows(tbl, tid, idx, mask, row_tile=16,
@@ -288,7 +296,8 @@ class TestVectorPool:
         zero = jnp.zeros((19, 2, 4), jnp.float32)
         for pool in ("scalar", "vector"):
             res = ops.embedding_bag_stacked_op(tbl, idx, zero,
-                                               pool_mode=pool)
+                                               pool_mode=pool,
+                                               impl="interpret")
             st = eb.embedding_bag_stacked(tbl, idx, zero, row_block=128,
                                           pool_mode=pool, interpret=True,
                                           dma=True)
@@ -485,7 +494,7 @@ class TestStreamPlan:
         gid = jax.random.randint(jax.random.PRNGKey(1), (40, 4), 0, 2000,
                                  dtype=jnp.int32)
         plan = eb.build_stream_plan(2000, 16, gid, row_tile=16, rb=256)
-        tbl = jax.random.normal(jax.random.PRNGKey(2), (2000, 16))
+        tbl = jax.random.normal(jax.random.PRNGKey(2), (1, 2000, 16))
         w = jnp.ones((40, 4), jnp.float32)
         a = eb._stream_rows(tbl, gid, w, row_tile=16, rb=256,
                             interpret=True, out_dtype=jnp.float32)

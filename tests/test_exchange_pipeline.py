@@ -186,7 +186,7 @@ def shard_fn(b):
                             jnp.zeros((p, nb), jnp.int32))
     return out[None]
 
-got = compat.shard_map(shard_fn, mesh=mesh,
+got = jax.shard_map(shard_fn, mesh=mesh,
                        in_specs=(P("model", None, None),),
                        out_specs=P("model", None, None),
                        check_vma=False)(buf)

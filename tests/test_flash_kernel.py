@@ -31,7 +31,8 @@ def test_flash_kernel_sweep(h, kh, window, causal, softcap):
     k = jax.random.normal(ks[1], (b, s, kh, hd))
     v = jax.random.normal(ks[2], (b, s, kh, hd))
     out = ops.flash_attention_op(q, k, v, causal=causal, window=window,
-                                 softcap=softcap, cq=32, ck=32)
+                                 softcap=softcap, cq=32, ck=32,
+                                 impl="interpret")
     r = _ref(q, k, v, window, causal, softcap)
     assert jnp.allclose(out, r, atol=1e-4), (h, kh, window, causal, softcap)
 
@@ -41,7 +42,7 @@ def test_flash_kernel_uneven_chunks():
     q = jax.random.normal(ks[0], (1, 96, 2, 32))
     k = jax.random.normal(ks[1], (1, 96, 2, 32))
     v = jax.random.normal(ks[2], (1, 96, 2, 32))
-    out = ops.flash_attention_op(q, k, v, cq=32, ck=16)
+    out = ops.flash_attention_op(q, k, v, cq=32, ck=16, impl="interpret")
     r = _ref(q, k, v, 0, True, 0.0)
     assert jnp.allclose(out, r, atol=1e-4)
 
@@ -51,7 +52,7 @@ def test_flash_kernel_bf16():
     q = jax.random.normal(ks[0], (1, 64, 2, 16), jnp.bfloat16)
     k = jax.random.normal(ks[1], (1, 64, 2, 16), jnp.bfloat16)
     v = jax.random.normal(ks[2], (1, 64, 2, 16), jnp.bfloat16)
-    out = ops.flash_attention_op(q, k, v, cq=32, ck=32)
+    out = ops.flash_attention_op(q, k, v, cq=32, ck=32, impl="interpret")
     r = _ref(q, k, v, 0, True, 0.0)
     assert jnp.allclose(out.astype(jnp.float32), r.astype(jnp.float32),
                         atol=3e-2)

@@ -105,7 +105,8 @@ class TestStackedEmbeddingBag:
         idx = jax.random.randint(ks[1], (b, t, hot), 0, r)
         mask = (jax.random.uniform(ks[2], (b, t, hot)) < 0.6) \
             .astype(jnp.float32)
-        out = ops.embedding_bag_stacked_op(tbl, idx, mask, batch_tile=16)
+        out = ops.embedding_bag_stacked_op(tbl, idx, mask, batch_tile=16,
+                                           impl="interpret")
         want = ref.embedding_bag_stacked_ref(tbl, idx, mask)
         assert out.shape == (b, t, s)
         assert jnp.allclose(out, want, atol=1e-4)
@@ -115,10 +116,11 @@ class TestStackedEmbeddingBag:
         tbl = jax.random.normal(ks[0], (4, 50, 8))
         idx = jax.random.randint(ks[1], (16, 4, 3), 0, 50)
         mask = jnp.ones((16, 4, 3), jnp.float32)
-        stacked = ops.embedding_bag_stacked_op(tbl, idx, mask, batch_tile=16)
+        stacked = ops.embedding_bag_stacked_op(tbl, idx, mask, batch_tile=16,
+                                               impl="interpret")
         for ti in range(4):
             single = ops.embedding_bag_op(tbl[ti], idx[:, ti], mask[:, ti],
-                                          batch_tile=16)
+                                          batch_tile=16, impl="interpret")
             assert jnp.allclose(stacked[:, ti], single, atol=1e-5), ti
 
     def test_apply_emb_backend_dispatch(self):

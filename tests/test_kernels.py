@@ -14,7 +14,8 @@ class TestDotInteraction:
     @pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16])
     def test_sweep(self, b, f, s, dtype):
         z = jax.random.normal(jax.random.PRNGKey(0), (b, f, s), dtype)
-        out = ops.dot_interaction_op(z, batch_tile=min(64, b))
+        out = ops.dot_interaction_op(z, batch_tile=min(64, b),
+                                     impl="interpret")
         r = ref.dot_interaction_ref(z)
         tol = 1e-4 if dtype == jnp.float32 else 3e-2
         assert out.shape == (b, f * (f - 1) // 2)
@@ -27,7 +28,7 @@ class TestDotInteraction:
         # padded internally (mirroring the embedding-bag kernels) so odd
         # serving batch sizes run through the dense stage
         z = jax.random.normal(jax.random.PRNGKey(3), (b, 4, 8))
-        out = ops.dot_interaction_op(z, batch_tile=64)
+        out = ops.dot_interaction_op(z, batch_tile=64, impl="interpret")
         r = ref.dot_interaction_ref(z)
         assert out.shape == r.shape
         assert jnp.allclose(out, r, atol=1e-4)
@@ -41,7 +42,8 @@ class TestEmbeddingBag:
         tbl = jax.random.normal(key, (r, s))
         idx = jax.random.randint(key, (b, hot), 0, r)
         mask = (jax.random.uniform(key, (b, hot)) < 0.7).astype(jnp.float32)
-        out = ops.embedding_bag_op(tbl, idx, mask, batch_tile=min(32, b))
+        out = ops.embedding_bag_op(tbl, idx, mask, batch_tile=min(32, b),
+                                   impl="interpret")
         assert jnp.allclose(out, ref.embedding_bag_ref(tbl, idx, mask),
                             atol=1e-4)
 
@@ -54,7 +56,8 @@ class TestEmbeddingBag:
         tbl = jax.random.normal(k1, (r, 16))
         idx = jax.random.randint(k2, (b, hot), 0, r)
         mask = (jax.random.uniform(k3, (b, hot)) < 0.5).astype(jnp.float32)
-        out = ops.embedding_bag_op(tbl, idx, mask, batch_tile=b)
+        out = ops.embedding_bag_op(tbl, idx, mask, batch_tile=b,
+                                   impl="interpret")
         assert jnp.allclose(out, ref.embedding_bag_ref(tbl, idx, mask),
                             atol=1e-4)
 
@@ -62,7 +65,8 @@ class TestEmbeddingBag:
         tbl = jax.random.normal(jax.random.PRNGKey(0), (50, 8))
         idx = jnp.zeros((16, 3), jnp.int32)
         mask = jnp.zeros((16, 3), jnp.float32)
-        out = ops.embedding_bag_op(tbl, idx, mask, batch_tile=16)
+        out = ops.embedding_bag_op(tbl, idx, mask, batch_tile=16,
+                                   impl="interpret")
         assert jnp.allclose(out, 0.0)
 
 
@@ -78,7 +82,8 @@ class TestRwkv6Wkv:
         logw = -jnp.exp(jax.random.normal(ks[3], (b, s, h, K)))
         u = jax.random.normal(ks[4], (h, K)) * 0.5
         s0 = jax.random.normal(ks[5], (b, h, K, K)) * 0.1
-        out, sout = ops.rwkv6_wkv_op(r, k, v, logw, u, s0, chunk=chunk)
+        out, sout = ops.rwkv6_wkv_op(r, k, v, logw, u, s0, chunk=chunk,
+                                     impl="interpret")
         ro, rs = ref.rwkv6_wkv_ref(r, k, v, logw, u, s0)
         assert jnp.allclose(out, ro, atol=5e-4), (b, s, h, chunk)
         assert jnp.allclose(sout, rs, atol=5e-4)
@@ -94,7 +99,8 @@ class TestRwkv6Wkv:
         logw = jnp.full((b, s, h, K), -50.0)  # state dies each step
         u = jnp.ones((h, K))
         s0 = jnp.zeros((b, h, K, K))
-        out, _ = ops.rwkv6_wkv_op(r, k, v, logw, u, s0, chunk=16)
+        out, _ = ops.rwkv6_wkv_op(r, k, v, logw, u, s0, chunk=16,
+                                  impl="interpret")
         ro, _ = ref.rwkv6_wkv_ref(r, k, v, logw, u, s0)
         assert bool(jnp.all(jnp.isfinite(out)))
         assert jnp.allclose(out, ro, atol=1e-4)
@@ -112,7 +118,7 @@ def test_kernels_match_model_usage():
     logw = -jnp.exp(jax.random.normal(ks[3], (b, s, h, K)))
     u = jax.random.normal(ks[4], (h, K)) * 0.5
     s0 = jnp.zeros((b, h, K, K))
-    o1, s1 = ops.rwkv6_wkv_op(r, k, v, logw, u, s0, chunk=32)
+    o1, s1 = ops.rwkv6_wkv_op(r, k, v, logw, u, s0, chunk=32, impl="interpret")
     o2, s2 = wkv_chunked(r, k, v, logw, u, s0, chunk=32)
     assert jnp.allclose(o1, o2, atol=5e-4)
     assert jnp.allclose(s1, s2, atol=5e-4)
