@@ -405,8 +405,9 @@ def alltoallv_fused(buf, axis: str = "model"):
     source q sent here.  Counts, ids, scales all ride inside the slot —
     no side collectives (vs the up-to-4 per-leaf ``alltoallv_ragged``
     issues)."""
-    return jax.lax.all_to_all(buf, axis, split_axis=0, concat_axis=0,
-                              tiled=True)
+    with jax.named_scope("exchange"):
+        return jax.lax.all_to_all(buf, axis, split_axis=0, concat_axis=0,
+                                  tiled=True)
 
 
 def ring_exchange(buf, axis: str, n_dest: int, consume, init):
@@ -435,7 +436,9 @@ def ring_exchange(buf, axis: str, n_dest: int, consume, init):
     out = init
     for r in range(1, p):
         perm = [(i, (i + r) % p) for i in range(p)]
-        chunk = jax.lax.ppermute(take(jax.lax.rem(m + r, p)), axis, perm)
+        with jax.named_scope("exchange"):
+            chunk = jax.lax.ppermute(take(jax.lax.rem(m + r, p)), axis,
+                                     perm)
         out = consume(out, *ready)
         ready = (jax.lax.rem(m - r + p, p), chunk)
     return consume(out, *ready)

@@ -399,7 +399,9 @@ def _stream_plan(gid, rb: int, total_rows: int, nbmax: int,
     nb_total = _n_blocks(total_rows, rows, rb)
     method = _resolve_plan_method(plan_method, L, nb_total, tiles)
     build = _plan_count if method == "count" else _plan_sort
-    return build(gid, rb, total_rows, nbmax, rows)
+    # the device scope the trace's plan-build ops are named by
+    with jax.named_scope("plan"):
+        return build(gid, rb, total_rows, nbmax, rows)
 
 
 def _stream_geometry(total_rows: int, s: int, n: int, hot: int,

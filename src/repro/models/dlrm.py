@@ -148,17 +148,19 @@ def apply_emb(tables, idx, mask, backend: str = "ref",
     backend = resolve_sparse_backend(backend)
     if backend != "ref":
         from repro.kernels.ops import embedding_bag_stacked_op
-        return embedding_bag_stacked_op(tables, idx.astype(jnp.int32),
-                                        mask, impl=backend,
-                                        row_block=row_block,
-                                        pool_mode=pool_mode, plan=plan)
+        with jax.named_scope("pool"):
+            return embedding_bag_stacked_op(tables, idx.astype(jnp.int32),
+                                            mask, impl=backend,
+                                            row_block=row_block,
+                                            pool_mode=pool_mode, plan=plan)
     if plan is not None:
         raise ValueError("apply_emb: a precomputed stream plan only "
                          "applies to the kernel backends, not 'ref'")
     # shared with the kernel oracle so every backend clips OOB ids the
     # same way
     from repro.kernels.ref import embedding_bag_stacked_ref
-    return embedding_bag_stacked_ref(tables, idx, mask)
+    with jax.named_scope("pool"):
+        return embedding_bag_stacked_ref(tables, idx, mask)
 
 
 @dataclasses.dataclass
@@ -201,14 +203,15 @@ def apply_emb_rows(tables, tid, idx, mask, backend: str = "ref",
     packed rows of a production-size stack DMA only the row blocks they
     touch."""
     backend = resolve_sparse_backend(backend)
-    if backend != "ref":
-        from repro.kernels.ops import embedding_bag_rows_op
-        return embedding_bag_rows_op(tables, tid.astype(jnp.int32),
-                                     idx.astype(jnp.int32), mask,
-                                     impl=backend, row_block=row_block,
-                                     pool_mode=pool_mode)
-    from repro.kernels.ref import embedding_bag_rows_ref
-    return embedding_bag_rows_ref(tables, tid, idx, mask)
+    with jax.named_scope("pool"):
+        if backend != "ref":
+            from repro.kernels.ops import embedding_bag_rows_op
+            return embedding_bag_rows_op(tables, tid.astype(jnp.int32),
+                                         idx.astype(jnp.int32), mask,
+                                         impl=backend, row_block=row_block,
+                                         pool_mode=pool_mode)
+        from repro.kernels.ref import embedding_bag_rows_ref
+        return embedding_bag_rows_ref(tables, tid, idx, mask)
 
 
 def resolve_pipeline(pipeline: str, n_shards: int) -> str:
@@ -810,6 +813,7 @@ def forward_distributed(params, cfg: DLRMConfig, dense, idx, mask, *,
                 {"rvec": bk["rvec"], "rgid": bk["rgid"], "rcs": bk["rcs"],
                  "rcnt": cnts.reshape(n_shards, 1)}, rlayout)
 
+        @jax.named_scope("stage_a")
         def stage_a(x):
             j, d, ix, mk = x[:4]
             xi = 4
@@ -959,6 +963,7 @@ def forward_distributed(params, cfg: DLRMConfig, dense, idx, mask, *,
             return a2a_mod.defuse_wire(
                 a2a_mod.defuse_wire(chunk, layout)["xrep"], rlayout)
 
+        @jax.named_scope("stage_b")
         def stage_b(recv, side):
             z0, hits = side
             staged = staged_m = staged_r = wbad = None
@@ -1300,11 +1305,12 @@ def build_forward_plans(params, cfg: DLRMConfig, idx, *,
     b_mb = idx.shape[0] // (n_data * mb)
     plan_struct = jax.eval_shape(per_mb, jax.ShapeDtypeStruct(
         (b_mb, t_loc, idx.shape[2]), jnp.int32))
-    return jax.shard_map(
-        plan_fn, mesh=mesh, in_specs=(sparse_spec,),
-        out_specs=jax.tree.map(lambda _: out_spec, plan_struct),
-        check_vma=False,
-    )(idx.astype(jnp.int32))
+    with jax.named_scope("plan"):
+        return jax.shard_map(
+            plan_fn, mesh=mesh, in_specs=(sparse_spec,),
+            out_specs=jax.tree.map(lambda _: out_spec, plan_struct),
+            check_vma=False,
+        )(idx.astype(jnp.int32))
 
 
 # ---------------------------------------------------------------------------

@@ -27,6 +27,7 @@ from repro.runtime.elastic import NodeFailure
 from repro.runtime.reshard import MIG_KEYS, ReshardExecutor
 from repro.runtime.straggler import (CapAutotuner, StragglerMonitor,
                                      detect_stragglers)
+from repro.serving import trace
 from repro.train import steps as steps_mod
 
 # host <-> step argument order of the delta wire leaves (sorted, matching
@@ -71,10 +72,12 @@ class ServeStats:
     quarantined_served: int = 0  # bags that touched a quarantined row
     wire_rejects: int = 0       # (dst, microbatch, src) segments rejected
     detection_lag_flushes: int = 0  # worst inject -> detect lag observed
-    # per-member exchange telemetry (EWMA pooled rows / exchanged bytes,
-    # dispatch_stats-sourced) — lists so the JSON view keeps the member axis
+    # -- pooling ledger ----------------------------------------------------
+    pooled_indices: int = 0     # valid indices pooled, padded rows included
+    padded_indices: int = 0     # of those, the ones in padded rows
+    # per-member exchange telemetry (EWMA pooled rows) — a list so the JSON
+    # view keeps the member axis
     member_rows: list = dataclasses.field(default_factory=list)
-    member_bytes: list = dataclasses.field(default_factory=list)
 
     @property
     def throughput_rps(self) -> float:
@@ -351,6 +354,9 @@ class DLRMEngine:
         pipe = self.exchange_pipeline
         rblk, pool = self.row_block, self.pool_mode
         deg, fb = self.degraded_members, self.degraded_fallback
+        # the step closes over settings only, never over the engine: the
+        # trace registry (trace.note_step) may keep it past the engine
+        unroll = self.unroll
         # diagnostics cost a full-batch miss re-probe + collectives:
         # trace them only when something consumes them — drop monitoring
         # (explicit ragged), the autotuner (auto WITH a cache; cacheless
@@ -404,7 +410,7 @@ class DLRMEngine:
             deltas = dict(zip(DELTA_KEYS, rest)) if rest else None
             res = dlrm_mod.forward_distributed(
                 params, cfg, dense, idx, mask, bound=bound,
-                microbatches=microbatches, unroll=self.unroll,
+                microbatches=microbatches, unroll=unroll,
                 cache=cache, wire_dtype=wire,
                 exchange=ex, ragged_cap=cap, exchange_pipeline=pipe,
                 row_block=rblk, pool_mode=pool, plan=plan, deltas=deltas,
@@ -504,25 +510,27 @@ class DLRMEngine:
         ``total_s`` clips each interval at the previous batch's end so it
         sums non-overlapping busy time (throughput_rps stays honest even
         though pipelined steps overlap request accumulation)."""
-        out = np.asarray(out)
-        end = done_t if done_t is not None else time.perf_counter()
-        self.monitor.observe(end - t0)
-        if diag:
-            self.cap_tuner.observe(int(diag[0]), int(diag[1]))
-            if len(diag) > 2:
-                self.stats.approx_rows += int(diag[2])
-        if self.degraded_members:
-            self.stats.degraded_batches += 1
-        self.stats.batches += 1
-        self.stats.requests += n
-        self.stats.total_s += end - max(t0, self._last_finish_t)
-        self._last_finish_t = max(self._last_finish_t, end)
-        if self.exchange == "auto" and \
-                self.stats.batches % self.retune_every == 0:
-            self.retune_cap()
-        if step_no is not None:
-            self._after_flush(step_no, end - t0)
-            self.maybe_rebalance()
+        with trace.span("engine.wait", flush=step_no):
+            out = np.asarray(out)
+        with trace.span("engine.account", flush=step_no):
+            end = done_t if done_t is not None else time.perf_counter()
+            self.monitor.observe(end - t0)
+            if diag:
+                self.cap_tuner.observe(int(diag[0]), int(diag[1]))
+                if len(diag) > 2:
+                    self.stats.approx_rows += int(diag[2])
+            if self.degraded_members:
+                self.stats.degraded_batches += 1
+            self.stats.batches += 1
+            self.stats.requests += n
+            self.stats.total_s += end - max(t0, self._last_finish_t)
+            self._last_finish_t = max(self._last_finish_t, end)
+            if self.exchange == "auto" and \
+                    self.stats.batches % self.retune_every == 0:
+                self.retune_cap()
+            if step_no is not None:
+                self._after_flush(step_no, end - t0)
+                self.maybe_rebalance()
         return out[:n]
 
     def _harvest(self):
@@ -534,7 +542,8 @@ class DLRMEngine:
             return None
         out, diag, n, t0, watcher, done, step_no = self._inflight
         self._inflight = None
-        watcher.join()
+        with trace.span("engine.wait", flush=step_no):
+            watcher.join()
         if done["err"] is not None:
             err = done["err"]
             raise RuntimeError(
@@ -542,6 +551,12 @@ class DLRMEngine:
                 f"flush #{step_no}): {err!r}") from err
         return self._finish_batch(out, diag, n, t0, done["t"],
                                   step_no=step_no)
+
+    @property
+    def steps(self) -> int:
+        """Batches dispatched so far: the flush number the next batch
+        gets, which its spans and requests carry."""
+        return self._flushes
 
     def flush(self):
         """Run the pending batch.  Inline mode returns its CTRs; under
@@ -551,56 +566,78 @@ class DLRMEngine:
         one."""
         if not self._pending:
             return self._harvest()
-        n = len(self._pending)
-        pad = self.batch_size - n
-        d = np.stack([p[0] for p in self._pending] +
-                     [self._pending[-1][0]] * pad)
-        i = np.stack([p[1] for p in self._pending] +
-                     [self._pending[-1][1]] * pad)
-        m = np.stack([p[2] for p in self._pending] +
-                     [self._pending[-1][2]] * pad)
-        self._pending.clear()
         step_no = self._flushes
         self._flushes += 1
+        with trace.span("engine.flush", flush=step_no) as sp:
+            return self._flush(step_no, sp)
+
+    def _flush(self, step_no, sp):
+        n = len(self._pending)
+        pad = self.batch_size - n
+        last = self._pending[-1]
+        with trace.span("engine.stack", flush=step_no):
+            d = np.stack([p[0] for p in self._pending] + [last[0]] * pad)
+            i = np.stack([p[1] for p in self._pending] + [last[1]] * pad)
+            m = np.stack([p[2] for p in self._pending] + [last[2]] * pad)
+        self._pending.clear()
+        # padded rows copy the last request, so they pool its indices
+        padded = pad * int(np.count_nonzero(np.asarray(last[2]) > 0))
         t0 = time.perf_counter()
         if not self.plan_pipeline:
-            out, diag = self._run_batch(d, i, m, step_no)
+            out, diag, pooled = self._run_batch(d, i, m, step_no)
+            self._count_pooled(sp, n, pooled, padded)
             return self._finish_batch(out, diag, n, t0, step_no=step_no)
         # flush n+1's plan is dispatched while flush n (the in-flight
         # entry harvested below) still occupies the device — the plan
         # build overlaps stage_a compute instead of serializing with it
+        mesh = self._active_mesh()
         with self._mesh_ctx():
-            fitted = self._fit_batch(d, i, m)
-            args = self._step_args(*fitted)
-            # a lookahead-staged plan (stage_plan) is adopted when its
-            # batch digest matches what we are about to dispatch; a stale
-            # stage (queue churn between peek and flush) replans inline
-            staged, self._staged_plan = self._staged_plan, None
-            if staged is not None and \
-                    staged[0] == self._plan_digest(fitted[1]):
-                plan = staged[1]
-                self.plan_stage_hits += 1
-            else:
-                plan = self._plan_fn(self.params, args[2])
-            out, *diag = self._step(*args, plan)
+            with trace.span("engine.prepare", flush=step_no):
+                fitted = self._fit_batch(d, i, m)
+                args = self._step_args(*fitted)
+            self._count_pooled(sp, n, int(self._live_counts(fitted[2]).sum()),
+                               padded)
+            with trace.span("engine.plan", flush=step_no):
+                # a lookahead-staged plan (stage_plan) is adopted when its
+                # batch digest matches what we are about to dispatch; a
+                # stale stage (queue churn between peek and flush) replans
+                # inline
+                staged, self._staged_plan = self._staged_plan, None
+                if staged is not None and \
+                        staged[0] == self._plan_digest(fitted[1]):
+                    plan = staged[1]
+                    self.plan_stage_hits += 1
+                else:
+                    trace.note_step(self._plan_fn, (self.params, args[2]),
+                                    mesh)
+                    plan = self._plan_fn(self.params, args[2])
+            trace.note_step(self._step, args + (plan,), mesh)
+            with trace.span("engine.dispatch", flush=step_no):
+                out, *diag = self._step(*args, plan)
         # a daemon watcher blocks on the async result off the main thread
         # and stamps true completion, so the harvested batch's latency is
         # dispatch -> device completion, not harvest-to-harvest wall time
         done = {"t": None, "err": None}
 
         def _watch(o=out, d=done):
-            try:
-                jax.block_until_ready(o)
-            except Exception as e:   # surfaced at the NEXT harvest
-                d["err"] = e
-            finally:
-                d["t"] = time.perf_counter()
+            with trace.span("engine.watch", flush=step_no):
+                try:
+                    jax.block_until_ready(o)
+                except Exception as e:   # surfaced at the NEXT harvest
+                    d["err"] = e
+                finally:
+                    d["t"] = time.perf_counter()
 
         watcher = threading.Thread(target=_watch, daemon=True)
         watcher.start()
         prev = self._harvest()
         self._inflight = (out, diag, n, t0, watcher, done, step_no)
         return prev
+
+    def _count_pooled(self, sp, n, pooled, padded):
+        self.stats.pooled_indices += pooled
+        self.stats.padded_indices += padded
+        sp.set(n=n, pooled=pooled, padded=padded)
 
     def drain(self):
         """Flush the pending queue AND the pipeline: returns every CTR not
@@ -664,96 +701,109 @@ class DLRMEngine:
         eviction recovery.  The SAME requests are served no matter how
         many members die: a ``NodeFailure`` (raised by the injector, or
         by real collective monitoring) triggers evict() and the batch is
-        re-dispatched on the shrunken mesh — zero requests lost."""
+        re-dispatched on the shrunken mesh — zero requests lost.  Returns
+        (step output, diagnostics, valid indices pooled)."""
         for attempt in range(self.max_retries + 1):
             try:
-                if self.freshness is not None:
-                    # the atomic apply window sits BETWEEN flushes: rows
-                    # harvested last flush commit (or roll back) before
-                    # this flush's batch is dispatched
-                    self.freshness.apply(self, step_no)
-                if self.scrub is not None:
-                    # repair rows share the freshness apply window (and
-                    # run AFTER it, so a delta that already overwrote the
-                    # corruption wins); injected faults land before the
-                    # audit so the scrubber is exercised, not informed
-                    self.scrub.apply(self, step_no)
+                with trace.span("engine.prepare", flush=step_no):
+                    if self.freshness is not None:
+                        # the atomic apply window sits BETWEEN flushes:
+                        # rows harvested last flush commit (or roll back)
+                        # before this flush's batch is dispatched
+                        self.freshness.apply(self, step_no)
+                    if self.scrub is not None:
+                        # repair rows share the freshness apply window (and
+                        # run AFTER it, so a delta that already overwrote
+                        # the corruption wins); injected faults land before
+                        # the audit so the scrubber is exercised, not
+                        # informed
+                        self.scrub.apply(self, step_no)
+                        if self.faults is not None:
+                            for (_, t, r, b, tgt) in \
+                                    self.faults.bitflips(step_no):
+                                self._inject_bitflip(t, r, b, tgt,
+                                                     step_no)
+                        for g in self.scrub.audit(self, step_no):
+                            fs = self._flip_log.pop(g, None)
+                            if fs is not None:
+                                self.stats.detection_lag_flushes = max(
+                                    self.stats.detection_lag_flushes,
+                                    step_no - fs)
+                    # the cutover window sits between flushes too: once
+                    # every migrated row is banked and verified, the atomic
+                    # swap happens here, BEFORE this flush's batch is
+                    # dispatched
+                    resh = self.reshard
+                    if resh is not None and resh.try_commit(self, step_no):
+                        self._finish_cutover(resh)
+                    self._ensure_step()
                     if self.faults is not None:
-                        for (_, t, r, b, tgt) in \
-                                self.faults.bitflips(step_no):
-                            self._inject_bitflip(t, r, b, tgt, step_no)
-                    for g in self.scrub.audit(self, step_no):
-                        fs = self._flip_log.pop(g, None)
-                        if fs is not None:
-                            self.stats.detection_lag_flushes = max(
-                                self.stats.detection_lag_flushes,
-                                step_no - fs)
-                # the cutover window sits between flushes too: once every
-                # migrated row is banked and verified, the atomic swap
-                # happens here, BEFORE this flush's batch is dispatched
-                resh = self.reshard
-                if resh is not None and resh.try_commit(self, step_no):
-                    self._finish_cutover(resh)
-                self._ensure_step()
-                if self.faults is not None:
-                    self.faults.on_flush(step_no, mesh=self._active_mesh(),
-                                         exclude=self.degraded_members)
-                fd, fi, fm = self._fit_batch(d, i, m)
-                args = self._step_args(fd, fi, fm)
-                if self.freshness is not None:
-                    dw = self.freshness.next_wire(self, step_no)
-                    args = args + tuple(jnp.asarray(dw[k])
-                                        for k in DELTA_KEYS)
-                mig_live = self.reshard is not None and self.reshard.active
-                if mig_live:
-                    mw = self.reshard.next_wire(self, step_no)
-                    args = args + tuple(jnp.asarray(mw[k])
-                                        for k in MIG_KEYS)
-                if self.scrub is not None:
-                    rw = self.scrub.next_wire(self, step_no)
-                    args = args + tuple(jnp.asarray(rw[k])
-                                        for k in REP_KEYS)
-                    args = args + (jnp.asarray(
-                        self.scrub.quarantine_phys(self), jnp.int32),)
-                    args = args + (self._wire_flip_arg(step_no),)
-                if self._step_key[1]:        # with_inv
-                    args = args + (jnp.asarray(self.pmap.inv_array()),)
-                with self._mesh_ctx():
+                        self.faults.on_flush(
+                            step_no, mesh=self._active_mesh(),
+                            exclude=self.degraded_members)
+                    fd, fi, fm = self._fit_batch(d, i, m)
+                    args = self._step_args(fd, fi, fm)
+                    if self.freshness is not None:
+                        dw = self.freshness.next_wire(self, step_no)
+                        args = args + tuple(jnp.asarray(dw[k])
+                                            for k in DELTA_KEYS)
+                    mig_live = self.reshard is not None and \
+                        self.reshard.active
+                    if mig_live:
+                        mw = self.reshard.next_wire(self, step_no)
+                        args = args + tuple(jnp.asarray(mw[k])
+                                            for k in MIG_KEYS)
+                    if self.scrub is not None:
+                        rw = self.scrub.next_wire(self, step_no)
+                        args = args + tuple(jnp.asarray(rw[k])
+                                            for k in REP_KEYS)
+                        args = args + (jnp.asarray(
+                            self.scrub.quarantine_phys(self), jnp.int32),)
+                        args = args + (self._wire_flip_arg(step_no),)
+                    if self._step_key[1]:        # with_inv
+                        args = args + (
+                            jnp.asarray(self.pmap.inv_array()),)
+                trace.note_step(self._step, args, self._active_mesh())
+                with trace.span("engine.dispatch", flush=step_no), \
+                        self._mesh_ctx():
                     out, *diag = self._step(*args)
-                held_wbad = None
-                if self.scrub is not None:
-                    # wire flags + repair harvest ride LAST; the flags
-                    # bank one flush unread (same deferred-harvest
-                    # discipline as the riders: never sync the step we
-                    # just dispatched).  Processing is deferred to the
-                    # END of the flush — _note_wire may evict, and the
-                    # accounting below must see this batch's geometry
-                    held_wbad, self._held_wbad = \
-                        self._held_wbad, diag.pop()
-                    self.scrub.ingest(diag.pop(), self, step_no)
-                if mig_live:
-                    self.reshard.ingest(diag.pop(), self, step_no)
-                if self.freshness is not None:
-                    staged = diag.pop()
-                    self.freshness.ingest(staged, self, step_no)
-                    fr = self.freshness
-                    self.stats.rows_stale_served += \
-                        fr.count_stale_served(self, fi, fm)
-                    self.stats.rows_applied = fr.rows_applied
-                    self.stats.delta_rejects = fr.delta_rejects
-                    self.stats.apply_rollbacks = fr.rollbacks
-                    self.stats.versions_behind = fr.ledger.versions_behind
-                if self.scrub is not None:
-                    sc = self.scrub
-                    self.stats.blocks_scrubbed = sc.blocks_scrubbed
-                    self.stats.detections = sc.detections
-                    self.stats.repaired_rows = sc.repaired_rows
-                    self.stats.quarantined_served += \
-                        sc.count_quarantined_served(self, fi, fm)
-                self._observe_load(fm, step_no)
-                if held_wbad is not None:
-                    self._note_wire(held_wbad, step_no)
-                return out, diag
+                with trace.span("engine.account", flush=step_no):
+                    held_wbad = None
+                    if self.scrub is not None:
+                        # wire flags + repair harvest ride LAST; the flags
+                        # bank one flush unread (same deferred-harvest
+                        # discipline as the riders: never sync the step we
+                        # just dispatched).  Processing is deferred to the
+                        # END of the flush — _note_wire may evict, and the
+                        # accounting below must see this batch's geometry
+                        held_wbad, self._held_wbad = \
+                            self._held_wbad, diag.pop()
+                        self.scrub.ingest(diag.pop(), self, step_no)
+                    if mig_live:
+                        self.reshard.ingest(diag.pop(), self, step_no)
+                    if self.freshness is not None:
+                        staged = diag.pop()
+                        self.freshness.ingest(staged, self, step_no)
+                        fr = self.freshness
+                        self.stats.rows_stale_served += \
+                            fr.count_stale_served(self, fi, fm)
+                        self.stats.rows_applied = fr.rows_applied
+                        self.stats.delta_rejects = fr.delta_rejects
+                        self.stats.apply_rollbacks = fr.rollbacks
+                        self.stats.versions_behind = \
+                            fr.ledger.versions_behind
+                    if self.scrub is not None:
+                        sc = self.scrub
+                        self.stats.blocks_scrubbed = sc.blocks_scrubbed
+                        self.stats.detections = sc.detections
+                        self.stats.repaired_rows = sc.repaired_rows
+                        self.stats.quarantined_served += \
+                            sc.count_quarantined_served(self, fi, fm)
+                    live = self._live_counts(fm)
+                    self._observe_load(live, step_no)
+                    if held_wbad is not None:
+                        self._note_wire(held_wbad, step_no)
+                return out, diag, int(live.sum())
             except NodeFailure as e:
                 if attempt >= self.max_retries:
                     raise
@@ -845,16 +895,22 @@ class DLRMEngine:
 
     # -- skew-aware placement: telemetry, policy, online resharding --------
 
-    def _observe_load(self, fm, step_no):
-        """Per-table / per-member load telemetry from the flushed batch's
-        live (unmasked) bags — the placement cost model's input and the
-        ``ServeStats`` imbalance mirror.  ``fm`` is the FITTED (already
-        permuted) mask, so the physical-column counts are mapped back to
-        ORIGINAL table space before they feed the EWMA: observations
-        survive cutovers and evictions unchanged."""
-        p, t_pad, _, _ = self._exchange_geometry()
-        live = np.asarray(np.asarray(fm) > 0).sum(axis=(0, 2)) \
+    @staticmethod
+    def _live_counts(fm) -> np.ndarray:
+        """Valid (unmasked) indices per physical table column of a fitted
+        batch mask, padded rows included."""
+        return np.asarray(np.asarray(fm) > 0).sum(axis=(0, 2)) \
             .astype(np.float64)
+
+    def _observe_load(self, live, step_no):
+        """Per-table / per-member load telemetry from the flushed batch's
+        live (unmasked) indices per physical table column (``live``, from
+        :meth:`_live_counts` of the FITTED, already permuted mask) — the
+        placement cost model's input and the ``ServeStats`` imbalance
+        mirror.  The counts are mapped back to ORIGINAL table space before
+        they feed the EWMA: observations survive cutovers and evictions
+        unchanged."""
+        p, t_pad, _, _ = self._exchange_geometry()
         pm = self._pmap
         if pm is not None and not pm.is_identity:
             orig = np.empty_like(live)
@@ -875,11 +931,6 @@ class DLRMEngine:
             self._member_ewma = 0.75 * self._member_ewma + 0.25 * mrows
         st = self.stats
         st.member_rows = [float(x) for x in self._member_ewma]
-        st.member_bytes = [
-            float(a2a_mod.dispatch_stats(
-                np.asarray([c]), int(np.ceil(max(float(c), 1.0))),
-                row_b).useful_bytes)
-            for c in self._member_ewma]
         st.imbalance_ratio = plc_mod.imbalance(self._member_ewma)
         if self.faults is not None:
             base = self.monitor.percentile(0.5) or 1e-3

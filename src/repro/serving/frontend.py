@@ -63,6 +63,7 @@ from typing import Callable, Optional
 
 import numpy as np
 
+from repro.serving import trace
 from repro.serving.engine import ServeStats
 
 ADMITTED = "admitted"
@@ -87,7 +88,9 @@ class SubmitResult:
 
 @dataclasses.dataclass(frozen=True)
 class ServedRequest:
-    """One completed request with its full latency decomposition."""
+    """One completed request with its full latency decomposition.
+    ``flush`` is the engine flush that served it (-1 when the engine does
+    not number its flushes): the key of that flush's trace spans."""
     request_id: int
     tenant: str
     ctr: float
@@ -96,6 +99,7 @@ class ServedRequest:
     t_done: float
     deadline: float
     degraded: bool
+    flush: int = -1
 
     @property
     def queue_delay_s(self) -> float:
@@ -583,61 +587,68 @@ class ServingFrontend:
         self._qshed(self.shed_cutoff(now))
 
     def _dispatch(self, now: float) -> list:
-        self._shed_pass(now)
-        if self._qlen() == 0:
-            self.stats.queued = 0
-            return []
-        b = self.engine.batch_size
-        batch = self._qtake(b)
-        self.stats.queued = self._qlen()
-        if self.faults is not None and hasattr(self.faults, "on_dequeue"):
-            self.faults.on_dequeue(self._n_dispatched)
-        t0 = self.now()
-        out = None
-        for r in batch:
-            ret = self.engine.submit(r.dense, r.idx, r.mask)
-            if ret is not None:
-                out = ret                    # engine auto-flushed at B
-        if len(batch) < b:
-            # partial batch: the engine did not auto-flush — do it
-            # explicitly (exactly once; a full batch already flushed, and
-            # a pipelined first flush legitimately returns None)
-            ret = self.engine.flush()
-            if ret is not None:
-                out = ret
-        t1 = self.now()
-        self._observe_flush(t1 - t0)
-        self._dispatched.append((batch, t0, self.stats.level))
-        self.stats.inflight += len(batch)
-        self._n_dispatched += 1
+        with trace.span("frontend.dispatch") as sp:
+            self._shed_pass(now)
+            if self._qlen() == 0:
+                self.stats.queued = 0
+                return []
+            b = self.engine.batch_size
+            batch = self._qtake(b)
+            self.stats.queued = self._qlen()
+            flush = getattr(self.engine, "steps", -1)
+            sp.set(flush=flush, n=len(batch), queued=self.stats.queued)
+            if self.faults is not None and \
+                    hasattr(self.faults, "on_dequeue"):
+                self.faults.on_dequeue(self._n_dispatched)
+            t0 = self.now()
+            out = None
+            for r in batch:
+                ret = self.engine.submit(r.dense, r.idx, r.mask)
+                if ret is not None:
+                    out = ret                # engine auto-flushed at B
+            if len(batch) < b:
+                # partial batch: the engine did not auto-flush — do it
+                # explicitly (exactly once; a full batch already flushed,
+                # and a pipelined first flush legitimately returns None)
+                ret = self.engine.flush()
+                if ret is not None:
+                    out = ret
+            t1 = self.now()
+            self._observe_flush(t1 - t0)
+            self._dispatched.append((batch, t0, self.stats.level, flush))
+            self.stats.inflight += len(batch)
+            self._n_dispatched += 1
         # inline engines return THIS batch; plan-pipelined engines return
         # the PREVIOUS one (or None on the first flush) — FIFO attribution
         # handles both
         return self._complete(out, t1) if out is not None else []
 
     def _complete(self, out, t_done: float) -> list:
-        batch, t_disp, level = self._dispatched.popleft()
-        out = np.asarray(out).reshape(-1)
-        if len(out) != len(batch):
-            raise RuntimeError(
-                f"batch attribution drifted: engine returned {len(out)} "
-                f"CTRs for a dispatched batch of {len(batch)}")
-        self.stats.inflight -= len(batch)
-        served = []
-        degraded = level >= LEVEL_DEGRADED
-        for r, ctr in zip(batch, out):
-            sr = ServedRequest(r.rid, r.tenant, float(ctr), r.t_arrive,
-                               t_disp, t_done, r.deadline, degraded)
-            if degraded:
-                self.stats.degraded_served += 1
-            else:
-                self.stats.served += 1
-            if not sr.in_slo:
-                self.stats.served_late += 1
-            self.stats.queue_delay.record(sr.queue_delay_s)
-            self.stats.e2e.record(sr.e2e_s)
-            self._recent_e2e.append(sr.e2e_s)
-            served.append(sr)
+        batch, t_disp, level, flush = self._dispatched.popleft()
+        with trace.span("frontend.complete", flush=flush, n=len(batch)):
+            out = np.asarray(out).reshape(-1)
+            if len(out) != len(batch):
+                raise RuntimeError(
+                    f"batch attribution drifted: engine returned "
+                    f"{len(out)} CTRs for a dispatched batch of "
+                    f"{len(batch)}")
+            self.stats.inflight -= len(batch)
+            served = []
+            degraded = level >= LEVEL_DEGRADED
+            for r, ctr in zip(batch, out):
+                sr = ServedRequest(r.rid, r.tenant, float(ctr), r.t_arrive,
+                                   t_disp, t_done, r.deadline, degraded,
+                                   flush)
+                if degraded:
+                    self.stats.degraded_served += 1
+                else:
+                    self.stats.served += 1
+                if not sr.in_slo:
+                    self.stats.served_late += 1
+                self.stats.queue_delay.record(sr.queue_delay_s)
+                self.stats.e2e.record(sr.e2e_s)
+                self._recent_e2e.append(sr.e2e_s)
+                served.append(sr)
         return served
 
     # -- graceful-degradation ladder --------------------------------------
