@@ -118,7 +118,7 @@ def bench_embedding_bag(csv=True, batch=128):
         # the streamed kernel at ITS auto block height everywhere: 1-2
         # blocks at VMEM-resident sizes (streaming's fixed cost where
         # streaming isn't needed), a real multi-block stream past them
-        rb = auto_row_block(rows, s, 4)
+        rb = auto_row_block(rows)
         fns = {"ref": lambda: ops.embedding_bag_stacked_op(
                    tbl, idx, mask, impl="ref"),
                "streamed": lambda: ops.embedding_bag_stacked_op(

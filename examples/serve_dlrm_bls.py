@@ -40,7 +40,7 @@ outputs, the knob trades collective-issue overhead against overlap.
 
 --row-block picks the embedding-bag kernel regime (DESIGN.md §1): 0 (auto)
 keeps small table blocks VMEM-resident and switches production-size tables
-to the double-buffered DMA row stream; > 0 forces streaming at that block
+to the DMA stream of 128-row lane tiles; > 0 forces streaming at that block
 height (useful for A/B-ing the streamed path at small scale).
 
 --pool-mode picks the kernel's pooling loop (DESIGN.md §1): 'vector' (what

@@ -112,7 +112,7 @@ class DLRMConfig:
     # --- fused sparse hot path (DESIGN.md) ---
     sparse_backend: str = "auto"    # ref | pallas | interpret | auto
     # embedding-bag row streaming (DESIGN.md §1): 0 = auto (VMEM-resident
-    # table blocks when they fit, double-buffered DMA row streaming
+    # table blocks when they fit, DMA streaming of 128-row lane tiles
     # otherwise), > 0 = forced streaming at that block height, -1 = forced
     # resident (fails loudly when the table block cannot fit VMEM)
     row_block: int = 0

@@ -25,19 +25,23 @@ Two regimes, one knob (``row_block``, DESIGN.md §1):
 
 * **DMA-streamed** — production-size tables cannot be resident, so the
   table stays in HBM (``memory_space=ANY``) and the kernel streams
-  ``(s, row_block)`` blocks through TWO VMEM scratch slots with
-  ``pltpu.make_async_copy``: the copy of block *n+1* is in flight while
-  block *n* is pooled.  Blocks never straddle tables (block *k* of table
-  *t* covers rows ``[k·rb, k·rb + rb)`` clamped into the table).  Indices
-  are pre-bucketed per block OUTSIDE the kernel (:func:`_stream_plan`):
-  grouping by block id makes each block's indices a contiguous segment of
-  the planned list, and empty blocks are compacted away entirely — each
-  grid step DMAs only the blocks its indices touch, so a skewed access
-  pattern streams a small head instead of the whole table.
+  ``(s, row_block)`` blocks with ``pltpu.make_async_copy`` through a ring
+  of K = :data:`STREAM_SLOTS` VMEM slots, one DMA semaphore each: the
+  copies run up to K blocks ahead of the block being pooled.  Blocks
+  never straddle tables (block *k* of table *t* covers rows ``[k·rb,
+  k·rb + rb)`` clamped into the table).  Indices are pre-bucketed per
+  block OUTSIDE the kernel (:func:`_stream_plan`): grouping by block id
+  makes each block's indices a contiguous segment of the planned list,
+  and empty blocks are compacted away entirely — each grid step DMAs only
+  the blocks its indices touch.  The auto block height is one 128-row
+  lane tile (:func:`auto_row_block`), not the tallest block the VMEM
+  budget holds: indices spread over a tall table touch one or two rows a
+  block at any height, so a taller block only copies more bytes nobody
+  reads, and the deep ring hides the latency of the many small copies.
 
-Per-tile plan scalars (row ids, staging slots, weights, block offsets and
-segment bounds) ride in SMEM, where the scalar core can use them as DMA
-offsets and loop bounds.  Each staged row lands in an f32 ``(hot, s,
+Per-tile plan scalars (row ids, staging slots, weights, compacted block
+per position, block offsets) ride in SMEM, where the scalar core can use
+them as DMA offsets and loop bounds.  Each staged row lands in an f32 ``(hot, s,
 128)`` VMEM accumulator — bag ``b``'s ``h``-th index in lane ``b`` of row
 ``h`` — written through refs, never through a loop-carried value.  The
 final reduce over ``hot`` runs in the reference order, so every kernel
@@ -46,16 +50,18 @@ order the rows arrived in.
 
 Each regime walks its indices in one of two **pool modes** (``pool_mode``):
 
-* ``scalar`` — a ``fori_loop`` over exactly one segment position per
-  step;
+* ``scalar`` — a ``fori_loop`` over exactly one position per step;
 * ``vector`` (the default under ``auto``) — ``chunk`` positions per step,
   statically unrolled so their independent loads, rotates and stores
-  overlap; a chunk's overhang past its segment stages rows that the
-  owning (later) block overwrites, and overhang past the list writes
-  nothing.
+  overlap.  The streamed kernel walks the planned list itself in chunks,
+  across block boundaries (a chunk waits for every block it reaches),
+  padding the list's tail with copies of its last entry; the resident
+  kernel pads with weight-0 positions that address no kept bag.
 
 The **stream plan** itself (:func:`_stream_plan`) has two builders behind
-one ``plan_method`` knob: ``sort`` (``O(L log L)`` argsort by row id) and
+one ``plan_method`` knob: ``sort`` (``O(L log L)``: one sort by row id,
+a cumsum of the block-change flags, and a second sort that compacts the
+block offsets — no search loop, no batched gather) and
 ``count`` (a counting sort keyed by block id: one histogram over ``nb``
 buckets whose prefix sum IS the segment-offset table — ``O(L · nb)``
 vectorized work, no comparison sort); ``auto`` picks ``count`` while
@@ -88,15 +94,16 @@ from typing import NamedTuple
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
 # VMEM budgets (bytes).  RESIDENT bounds the one (s, R) table block the
 # resident kernel keeps live per grid step (16 MiB scoped VMEM, minus the
 # staging accumulator and headroom -> 4 MiB ~ 16k rows at s=64 f32, the
-# DESIGN.md §1 number).  STREAM bounds the streamed kernel's two DMA slots
-# TOGETHER, and STAGE bounds the (tile, hot, s) f32 staging work — the
-# wrappers shrink row_block / batch_tile to respect them.
+# DESIGN.md §1 number).  STREAM bounds the streamed kernel's ring of DMA
+# slots TOGETHER, and STAGE bounds the (tile, hot, s) f32 staging work —
+# the wrappers shrink the ring / batch_tile to respect them.
 RESIDENT_VMEM_BYTES = 4 << 20
 STREAM_VMEM_BYTES = 4 << 20
 STAGE_VMEM_BYTES = 2 << 20
@@ -104,21 +111,22 @@ STAGE_VMEM_BYTES = 2 << 20
 # The vreg lane width.  Table rows are lane columns of the (s, R) view,
 # DMAs and tile loads move whole 128-lane tiles, and the staging
 # accumulator keeps one bag per lane, so a tile holds at most LANES bags.
+# One (s, LANES) tile is also the streamed kernel's auto fetch unit.
 LANES = 128
 
-# Vector-pool unroll: positions staged per loop step.  A chunk's overhang
-# past its segment is overwritten by the owning block, so a wider chunk
-# only pays off while segments are at least that long.
+# Streamed DMA ring depth: VMEM slots (and DMA semaphores) the streamed
+# kernel cycles through; its copies run up to STREAM_SLOTS blocks ahead of
+# the block being pooled.  Lane-tile blocks serve one to three indices
+# each, so a shallow queue would leave every copy's latency exposed; 16
+# slots of one (64, 128) f32 tile are 512 KiB (32 measured no faster on a
+# TPU v5e).  The ring shrinks to fit STREAM_VMEM_BYTES for taller explicit
+# blocks (never below two slots).
+STREAM_SLOTS = 16
+
+# Vector-pool unroll: positions staged per loop step.
 POOL_CHUNK = 8
 
 
-def _stream_pool_chunk(L: int, nbmax: int) -> int:
-    """Unroll width for the STREAMED vector pool: segments average
-    L / nbmax positions, so a full POOL_CHUNK would stage mostly
-    overhang when blocks are many.  Clamp the chunk to the expected
-    segment length — skew only makes hot-block segments longer, which
-    the fori over chunks absorbs."""
-    return max(1, min(POOL_CHUNK, -(-L // max(nbmax, 1))))
 
 # Counting-sort plan budget: the count method materializes a
 # (tiles, L, nb) one-hot running sum to rank indices within their block
@@ -132,20 +140,26 @@ def fits_resident(rows: int, s: int, itemsize: int) -> bool:
     return rows * s * itemsize <= RESIDENT_VMEM_BYTES
 
 
-def auto_row_block(total_rows: int, s: int, itemsize: int) -> int:
-    """Streamed block height: half the stream budget per DMA slot, rounded
-    down to whole 128-row lane tiles, clipped to the table."""
-    rb = max(LANES, (STREAM_VMEM_BYTES // (2 * s * itemsize))
-             // LANES * LANES)
-    return min(total_rows, rb)
+def auto_row_block(total_rows: int) -> int:
+    """Streamed block height: one 128-row lane tile, clipped to the table.
+
+    The fetch unit is set by the indices, not by the VMEM budget: a
+    streamed table is tall and its indices are spread, so a touched block
+    serves one or two of them whatever its height, and every byte past
+    the touched tile is copied for nothing.  Every touched lane tile lies
+    inside a touched taller block, so fetched bytes only fall as the unit
+    shrinks; the copy count rises, bounded by L a tile, and the DMA ring
+    (:data:`STREAM_SLOTS`) keeps those copies overlapped."""
+    return min(total_rows, LANES)
 
 
 def resolve_row_block(total_rows: int, s: int, itemsize: int,
                       row_block: int) -> tuple[bool, int]:
     """(streamed?, effective row_block) for a table of ``total_rows``.
 
-    row_block 0 = auto (resident iff the block fits RESIDENT_VMEM_BYTES),
-    > 0 = forced streaming at min(row_block, total_rows), -1 = forced
+    row_block 0 = auto (resident iff the block fits RESIDENT_VMEM_BYTES,
+    else streamed in :func:`auto_row_block` lane tiles), > 0 = forced
+    streaming at min(row_block, total_rows), -1 = forced
     resident (raises when the block cannot fit VMEM)."""
     if row_block == -1:
         if not fits_resident(total_rows, s, itemsize):
@@ -163,7 +177,7 @@ def resolve_row_block(total_rows: int, s: int, itemsize: int,
                          f"got {row_block}")
     if fits_resident(total_rows, s, itemsize):
         return False, total_rows
-    return True, auto_row_block(total_rows, s, itemsize)
+    return True, auto_row_block(total_rows)
 
 
 def resolve_pool_mode(pool_mode: str) -> str:
@@ -190,21 +204,20 @@ class StreamPlan(NamedTuple):
     handed to any entry point via ``plan=`` — and a plan built for a
     different block height or table cannot be consumed silently.
 
-    All array leaves are int32.  sid/pos/inv/cum are (tiles, L);
-    off/seg0/seg1 are (tiles, nbmax); nblk is (tiles, 1).  ``pos[p]`` is
-    the original flat position of planned entry ``p`` (its staging slot),
+    All array leaves are int32.  sid/pos/inv/cum are (tiles, L); off is
+    (tiles, nbmax); nblk is (tiles, 1).  ``pos[p]`` is the original flat
+    position of planned entry ``p`` (its staging slot and weight),
     ``inv`` is the inverse permutation (``inv[pos[p]] == p``), ``cum`` the
-    compacted block index owning each planned position.  Weights are NOT
-    part of the plan — they are permuted with ``pos`` at consumption time,
-    so a plan built from indices alone (before cache miss-masks exist)
-    stays valid.  Blocks are per table: ``off`` is the flat start row
+    compacted block index owning each planned position (non-decreasing:
+    block ``j``'s positions are one contiguous run).  Weights are NOT
+    part of the plan — the kernel reads them at ``pos`` at consumption
+    time, so a plan built from indices alone (before cache miss-masks
+    exist) stays valid.  Blocks are per table: ``off`` is the flat start row
     ``t·rows + c`` of a block that lies inside table ``t``."""
     sid: jax.Array     # planned (block-grouped) flat row ids
     pos: jax.Array     # original position of each planned entry
     inv: jax.Array     # planned position of each original entry
     off: jax.Array     # clamped flat start row per compacted block
-    seg0: jax.Array    # segment start per compacted block
-    seg1: jax.Array    # segment end per compacted block
     nblk: jax.Array    # compacted (touched) block count
     cum: jax.Array     # compacted block index per planned position
     rb: int = 0           # static: block height the plan bucketed for
@@ -212,11 +225,11 @@ class StreamPlan(NamedTuple):
     rows: int = 0         # static: rows per table (blocks never straddle)
 
 
-N_PLAN_LEAVES = 8          # array fields above; rb/total_rows/rows are aux
+N_PLAN_LEAVES = 6          # array fields above; rb/total_rows/rows are aux
 
 # rb/total_rows/rows are STATIC aux data, not traced leaves: tree
 # transforms (vmap over microbatches, shard_map redistribution, scan
-# slicing) map the eight index arrays and carry the geometry alongside,
+# slicing) map the six index arrays and carry the geometry alongside,
 # and _check_plan can raise at trace time when a plan meets a call with a
 # different row_block/table — shapes alone cannot always tell them apart
 # (nbmax clamps to L for any sufficiently tall table).
@@ -269,33 +282,37 @@ def _inverse_perm(perm):
         .reshape(tiles, L)
 
 
+def _sort_with(keys, *payload):
+    """Sort each row of ``keys`` (tiles, L) and carry ``payload`` arrays of
+    the same shape along: one sort, no gather (a batched gather costs the
+    TPU several times what the sort does)."""
+    return jax.lax.sort((keys, *payload), dimension=1, num_keys=1)
+
+
 def _plan_sort(gid, rb: int, total_rows: int, nbmax: int,
                rows: int) -> StreamPlan:
-    """The comparison-sort plan builder (PR 3): argsort by full row id,
-    segments recovered by searchsorted over the block-change prefix sum."""
+    """The comparison-sort plan builder: sort by full row id, carrying the
+    original positions along.  Each block's run starts where the sorted
+    list's block id changes; a second sort that moves those change
+    positions to the front, in order, carrying each one's block start
+    along, compacts the block offsets.  Every step is a sort or an
+    elementwise pass over L, so nothing grows with the ``nbmax`` blocks a
+    tile may touch."""
     tiles, L = gid.shape
-    pos = jnp.argsort(gid, axis=-1).astype(jnp.int32)
-    sid = jnp.take_along_axis(gid, pos, axis=-1)
-    inv = _inverse_perm(pos)
+    iota = jax.lax.broadcasted_iota(jnp.int32, (tiles, L), 1)
+    sid, pos = _sort_with(gid, iota)
+    _, inv = _sort_with(pos, iota)
     blk = _block_of(sid, rb, rows)                         # (tiles, L)
     first = jnp.concatenate(
         [jnp.ones((tiles, 1), bool), blk[:, 1:] != blk[:, :-1]], axis=-1)
     cum = jnp.cumsum(first.astype(jnp.int32), axis=-1) - 1  # compact index
     nblk = cum[:, -1:] + 1                                  # (tiles, 1)
-    jr = jnp.arange(nbmax, dtype=jnp.int32)
-    seg0 = jax.vmap(
-        lambda c: jnp.searchsorted(c, jr, side="left"))(cum)
-    seg1 = jax.vmap(
-        lambda c: jnp.searchsorted(c, jr, side="right"))(cum)
-    bid = jnp.take_along_axis(blk, jnp.minimum(seg0, L - 1), axis=-1)
-    off = _block_start(bid, rb, rows)
-    valid = jr[None, :] < nblk
-    zero = jnp.zeros((), jnp.int32)
+    _, off = _sort_with(jnp.where(first, iota, L + iota),
+                        _block_start(blk, rb, rows))
+    valid = jnp.arange(nbmax, dtype=jnp.int32)[None, :] < nblk
     return StreamPlan(
         sid, pos, inv,
-        jnp.where(valid, off, zero).astype(jnp.int32),
-        jnp.where(valid, seg0, zero).astype(jnp.int32),
-        jnp.where(valid, seg1, zero).astype(jnp.int32),
+        jnp.where(valid, off[:, :nbmax], 0).astype(jnp.int32),
         nblk.astype(jnp.int32), cum, rb=rb, total_rows=total_rows,
         rows=rows)
 
@@ -359,18 +376,12 @@ def _plan_count(gid, rb: int, total_rows: int, nbmax: int,
     arB = jnp.broadcast_to(jnp.arange(nb_total, dtype=jnp.int32),
                            (tiles, nb_total)).reshape(-1)
     bid = zB.at[cflat].set(arB, mode="drop").reshape(tiles, nbmax)
-    seg0 = zB.at[cflat].set(excl.astype(jnp.int32).reshape(-1),
-                            mode="drop").reshape(tiles, nbmax)
-    seg1 = zB.at[cflat].set((excl + hist).astype(jnp.int32).reshape(-1),
-                            mode="drop").reshape(tiles, nbmax)
     jr = jnp.arange(nbmax, dtype=jnp.int32)
     valid = jr[None, :] < nblk
     zero = jnp.zeros((), jnp.int32)
     off = jnp.where(valid, _block_start(bid, rb, rows), zero)
     cum = jnp.take_along_axis(cidx, _block_of(sid, rb, rows), axis=-1)
     return StreamPlan(sid, pos, inv, off.astype(jnp.int32),
-                      jnp.where(valid, seg0, zero),
-                      jnp.where(valid, seg1, zero),
                       nblk, cum.astype(jnp.int32),
                       rb=rb, total_rows=total_rows, rows=rows)
 
@@ -383,15 +394,16 @@ def _stream_plan(gid, rb: int, total_rows: int, nbmax: int,
     gid (tiles, L) int32 flat row ids in [0, total_rows).  Grouping by
     block id makes every block's indices one contiguous segment of the
     planned list, and blocks nobody indexes vanish from the compacted block
-    list — the kernel DMAs only touched blocks and walks each segment
+    list — the kernel DMAs only touched blocks and walks the planned list
     exactly once (total work stays L gathers per tile).  The last block's
     DMA start is clamped to ``total_rows - rb`` so a table whose row count
     is not a multiple of ``rb`` streams an overlapping final block instead
     of reading the next table (blocks never straddle tables: the kernel
     DMAs one table's (s, rb) window per block).
 
-    ``plan_method``: 'sort' (argsort by row id, O(L log L)), 'count'
-    (counting sort keyed by block id, O(L · nb) vectorized), 'auto' (count
+    ``plan_method``: 'sort' (sort by row id, O(L log L), block offsets
+    compacted by a second sort), 'count' (counting sort keyed by block
+    id, O(L · nb) vectorized), 'auto' (count
     under :data:`PLAN_COUNT_WORK`, sort past it).  ``rows`` is the height
     of one table of the stack (0: one table of ``total_rows``)."""
     tiles, L = gid.shape
@@ -406,17 +418,24 @@ def _stream_plan(gid, rb: int, total_rows: int, nbmax: int,
 
 def _stream_geometry(total_rows: int, s: int, n: int, hot: int,
                      row_tile: int, rb: int, rows: int):
-    """(nt, tiles, n_pad, L, nbmax, n_slots) — the one tiling both the
-    Pallas kernels and the jnp emulation (and any precomputed plan) share,
-    so a plan built outside can never disagree with the executor.  A tile
+    """(nt, tiles, n_pad, L, nbmax) — the one tiling both the Pallas
+    kernels and the jnp emulation (and any precomputed plan) share, so a
+    plan built outside can never disagree with the executor.  A tile
     holds at most LANES bags (one per accumulator lane)."""
     nt = min(_stage_tile(row_tile, n, hot, s), LANES)
     tiles = -(-n // nt)
     n_pad = tiles * nt
     L = nt * hot
     nbmax = min(_n_blocks(total_rows, rows, rb), L)
-    n_slots = min(2, nbmax)       # one whole-table block needs no partner
-    return nt, tiles, n_pad, L, nbmax, n_slots
+    return nt, tiles, n_pad, L, nbmax
+
+
+def _ring_slots(nbmax: int, rb: int, s: int, itemsize: int) -> int:
+    """Slots of the streamed kernel's DMA ring: :data:`STREAM_SLOTS`,
+    fewer where taller blocks would overrun STREAM_VMEM_BYTES (never below
+    two), and never more than the blocks one tile can touch."""
+    fit = STREAM_VMEM_BYTES // (max(rb, LANES) * s * itemsize)
+    return min(max(2, min(STREAM_SLOTS, fit)), nbmax)
 
 
 def build_stream_plan(total_rows: int, s: int, gid, *, row_tile: int,
@@ -429,12 +448,21 @@ def build_stream_plan(total_rows: int, s: int, gid, *, row_tile: int,
     ``rows`` is one table's height (0: a single table)."""
     n, hot = gid.shape
     rows = rows or total_rows
-    nt, tiles, n_pad, L, nbmax, _ = _stream_geometry(
+    nt, tiles, n_pad, L, nbmax = _stream_geometry(
         total_rows, s, n, hot, row_tile, rb, rows)
     if n_pad != n:
         gid = jnp.pad(gid, ((0, n_pad - n), (0, 0)))
     return _stream_plan(gid.reshape(tiles, L).astype(jnp.int32), rb,
                         total_rows, nbmax, plan_method, rows)
+
+
+def fetch_bytes(plan: StreamPlan, s: int, itemsize: int) -> tuple[int, int]:
+    """(touched blocks, bytes the streamed kernel copies) for ``plan``:
+    every compacted block is one (s, rb) DMA, so the bytes are
+    Σ nblk · rb · s · itemsize.  Reads the plan on the host — a
+    measurement helper, never called on the serving path."""
+    units = int(np.asarray(plan.nblk, np.int64).sum())
+    return units, units * plan.rb * s * itemsize
 
 
 def _check_plan(plan: StreamPlan, tiles: int, L: int, nbmax: int,
@@ -445,8 +473,7 @@ def _check_plan(plan: StreamPlan, tiles: int, L: int, nbmax: int,
     # different rb would gather silently-wrong rows
     meta = ("rb", "total_rows", "rows")
     want = {"sid": (tiles, L), "pos": (tiles, L), "inv": (tiles, L),
-            "off": (tiles, nbmax), "seg0": (tiles, nbmax),
-            "seg1": (tiles, nbmax), "nblk": (tiles, 1), "cum": (tiles, L),
+            "off": (tiles, nbmax), "nblk": (tiles, 1), "cum": (tiles, L),
             "rb": rb, "total_rows": total_rows, "rows": rows}
     got = {k: tuple(getattr(plan, k).shape) for k in want if k not in meta}
     got.update({k: getattr(plan, k) for k in meta})
@@ -514,29 +541,41 @@ def _check_lane_aligned(rows: int, rb: int):
 
 
 # ---------------------------------------------------------------------------
-# the streaming core: pre-bucketed indices + double-buffered DMA
+# the streaming core: pre-bucketed indices + a ring of in-flight DMAs
 # ---------------------------------------------------------------------------
 
 
-def _stream_kernel(sid_ref, pos_ref, w_ref, off_ref, seg0_ref, seg1_ref,
-                   nb_ref, tbl_ref, out_ref, buf, acc, sem, *, hot: int,
-                   rb: int, rows: int, chunk: int):
-    """Double-buffered HBM->VMEM block streaming over the (T, s, R) table
-    view.
+def _stream_kernel(sid_ref, pos_ref, w_ref, cum_ref, off_ref, nb_ref,
+                   tbl_ref, out_ref, buf, acc, sem, *, hot: int, rb: int,
+                   rows: int, chunk: int):
+    """HBM->VMEM block streaming over the (T, s, R) table view through a
+    ring of DMA slots, pooled position by position.
 
-    Plan refs are (1, ·) SMEM rows of this tile; tbl_ref lives in ANY/HBM;
-    buf is (n_slots, s, width) VMEM; acc the (hot, s, LANES) f32 staging
-    accumulator.  Block j+1's ``make_async_copy`` is started before block
-    j's rows are pooled, so the copy engine runs a block ahead of the
-    gather loop.  Each compacted block stages exactly its own segment of
-    the pre-bucketed index list (slot-per-index via ``pos``), and the
-    reduce over ``hot`` runs at the end — the reference summation order,
-    independent of block arrival order."""
-    n_slots, _, width = buf.shape   # 2 slots, or 1 when one block ships
+    Plan refs are (1, ·) SMEM rows of this tile: the planned row ids,
+    original positions and compacted blocks (padded to whole chunks with
+    copies of the last entry, which re-stage the same value), the
+    weights in ORIGINAL order (read at ``pos``, so no permuted copy is
+    ever made), block offsets and the block count.  tbl_ref lives in
+    ANY/HBM; buf is (n_slots, s, width) VMEM with one DMA semaphore per
+    slot; acc the (hot, s, LANES) f32 staging accumulator.
+
+    The walk runs over planned positions in chunks of ``chunk``, unrolled
+    so their independent loads, rotates and stores overlap however the
+    chunk straddles blocks.  Before a chunk whose first position lies in
+    block ``first``, every block below ``first + n_slots`` has its copy
+    started (their slots belong to blocks already pooled), and every block
+    the chunk reaches has its copy waited for; a chunk spans fewer than
+    ``n_slots`` blocks, so it never waits for a copy not yet started.
+    Each position stages its own row (slot-per-index via ``pos``), and
+    the reduce over ``hot`` runs at the end — the reference summation
+    order, independent of block arrival order."""
+    n_slots, _, width = buf.shape
     nb = nb_ref[0, 0]
+    n_chunks = sid_ref.shape[-1] // chunk
     aligned = rows % LANES == 0 and rb % LANES == 0
 
-    def dma(slot, j):
+    def dma(j):
+        slot = jax.lax.rem(j, n_slots)
         off = off_ref[0, j]
         t = off // rows
         c = off - t * rows
@@ -547,32 +586,35 @@ def _stream_kernel(sid_ref, pos_ref, w_ref, off_ref, seg0_ref, seg1_ref,
         return pltpu.make_async_copy(tbl_ref.at[t, :, pl.ds(c, rb)], dst,
                                      sem.at[slot])
 
-    acc[...] = jnp.zeros(acc.shape, acc.dtype)
-
-    @pl.when(nb > 0)
-    def _():
-        dma(0, 0).start()
-
-    def blk_body(j, carry):
-        slot = jax.lax.rem(j, n_slots)
-
-        @pl.when(j + 1 < nb)
-        def _():
-            dma(jax.lax.rem(j + 1, n_slots), j + 1).start()   # overlap
-        dma(slot, j).wait()
-        off = off_ref[0, j]
-        src = buf.at[slot]
-
-        def pool(p):
-            # overhang positions belong to later blocks: clip into this
-            # block, the owner overwrites their slot afterwards
-            loc = jnp.clip(sid_ref[0, p] - off, 0, rb - 1)
-            _stage_col(src, acc, loc, pos_ref[0, p], w_ref[0, p], hot=hot)
-
-        _walk(seg0_ref[0, j], seg1_ref[0, j], pool, chunk)
+    def start(j, carry):
+        dma(j).start()
         return carry
 
-    jax.lax.fori_loop(0, nb, blk_body, 0)
+    def wait(j, carry):
+        dma(j).wait()
+        return carry
+
+    acc[...] = jnp.zeros(acc.shape, acc.dtype)
+
+    def step(c, carry):
+        started, ready = carry
+        p0 = c * chunk
+        top = jnp.minimum(cum_ref[0, p0] + n_slots, nb)
+        jax.lax.fori_loop(started, top, start, 0)
+        need = jnp.minimum(cum_ref[0, p0 + chunk - 1] + 1, nb)
+        jax.lax.fori_loop(ready, need, wait, 0)
+        for k in range(chunk):
+            p = p0 + k
+            j = cum_ref[0, p]
+            q = pos_ref[0, p]
+            _stage_col(buf.at[jax.lax.rem(j, n_slots)], acc,
+                       sid_ref[0, p] - off_ref[0, j], q, w_ref[0, q],
+                       hot=hot)
+        return jnp.maximum(started, top), jnp.maximum(ready, need)
+
+    zero = jnp.zeros((), jnp.int32)
+    # a tile with no block (nblk 0) stages nothing and starts no copy
+    jax.lax.fori_loop(0, jnp.where(nb > 0, n_chunks, 0), step, (zero, zero))
     out_ref[...] = acc[...].sum(axis=0).astype(out_ref.dtype)
 
 
@@ -637,7 +679,7 @@ def _stream_rows(tables, gid, w, *, row_tile: int, rb: int,
     total_rows = t * rows
     n, hot = gid.shape
     vector = resolve_pool_mode(pool_mode) == "vector"   # validate up front
-    nt, tiles, n_pad, L, nbmax, n_slots = _stream_geometry(
+    nt, tiles, n_pad, L, nbmax = _stream_geometry(
         total_rows, s, n, hot, row_tile, rb, rows)
     if n_pad != n:
         gid = jnp.pad(gid, ((0, n_pad - n), (0, 0)))
@@ -647,29 +689,28 @@ def _stream_rows(tables, gid, w, *, row_tile: int, rb: int,
                             plan_method, rows)
     else:
         _check_plan(plan, tiles, L, nbmax, rb, total_rows, rows)
-    # weights are permuted into plan order HERE (an O(L) gather), never
-    # inside the plan — a plan built from indices alone stays valid for
-    # any miss-mask the cache produces at serving time
-    sw = jnp.take_along_axis(w.astype(jnp.float32).reshape(tiles, L),
-                             plan.pos, axis=-1)
+    w = w.astype(jnp.float32).reshape(tiles, L)
     use_dma = dma if dma is not None else not interpret
     if not use_dma:
+        # the emulation takes its weights in plan order (an O(L) gather)
+        sw = jnp.take_along_axis(w, plan.pos, axis=-1)
         return _stream_rows_jnp(tables.reshape(total_rows, s), plan, sw,
                                 nt=nt, hot=hot, rb=rb,
                                 out_dtype=out_dtype)[:n]
     if not interpret:
         _check_lane_aligned(rows, rb)
-    chunk = _stream_pool_chunk(L, nbmax) if vector else 1
-    sid, pos = plan.sid, plan.pos
-    if chunk > 1:
-        # the last chunk may overhang the list: padded slots address bag
-        # LANES, which matches no accumulator lane
-        ext = ((0, 0), (0, chunk))
-        sid = jnp.pad(sid, ext)
-        pos = jnp.pad(pos, ext, constant_values=LANES * hot)
-        sw = jnp.pad(sw, ext)
-    lp = sid.shape[1]
     width = max(rb, LANES)
+    n_slots = _ring_slots(nbmax, rb, s, jnp.dtype(tables.dtype).itemsize)
+    chunk = POOL_CHUNK if vector else 1
+    if n_slots < nbmax:
+        chunk = min(chunk, n_slots)   # a chunk must span fewer blocks
+    # weights are read at their original position inside the kernel, never
+    # permuted, so a plan built from indices alone stays valid for any
+    # miss-mask the cache produces at serving time
+    ext = ((0, 0), (0, -L % chunk))
+    sid, pos, cum = (jnp.pad(a, ext, mode="edge")
+                     for a in (plan.sid, plan.pos, plan.cum))
+    lp = sid.shape[1]
     out = pl.pallas_call(
         functools.partial(_stream_kernel, hot=hot, rb=rb, rows=rows,
                           chunk=chunk),
@@ -677,23 +718,21 @@ def _stream_rows(tables, gid, w, *, row_tile: int, rb: int,
         in_specs=[
             _smem_spec(lp),                         # planned row ids
             _smem_spec(lp),                         # staging slot per id
-            _smem_spec(lp),                         # planned weights
+            _smem_spec(L),                          # weights, original order
+            _smem_spec(lp),                         # compacted block per id
             _smem_spec(nbmax),                      # block start rows
-            _smem_spec(nbmax),                      # segment starts
-            _smem_spec(nbmax),                      # segment ends
             _smem_spec(1),                          # compacted block count
             pl.BlockSpec(memory_space=pl.ANY),      # table stays in HBM
         ],
         out_specs=pl.BlockSpec((None, s, LANES), lambda i: (i, 0, 0)),
         out_shape=jax.ShapeDtypeStruct((tiles, s, LANES), out_dtype),
         scratch_shapes=[
-            pltpu.VMEM((n_slots, s, width), tables.dtype),  # double buffer
+            pltpu.VMEM((n_slots, s, width), tables.dtype),  # DMA ring
             pltpu.VMEM((hot, s, LANES), jnp.float32),       # staging
             pltpu.SemaphoreType.DMA((n_slots,)),
         ],
         interpret=interpret,
-    )(*(a[:, None, :] for a in (sid, pos, sw, plan.off, plan.seg0,
-                                 plan.seg1, plan.nblk)),
+    )(*(a[:, None, :] for a in (sid, pos, w, cum, plan.off, plan.nblk)),
       jnp.swapaxes(tables, 1, 2))
     return out[:, :, :nt].transpose(0, 2, 1).reshape(n_pad, s)[:n]
 
@@ -789,8 +828,8 @@ def embedding_bag_stacked(tables, idx, mask, *, batch_tile: int = 64,
     tiles, and the (B,T,hot,s) broadcast-gather intermediate the pure-jnp
     reference materializes never exists.  Streamed regime (``row_block``):
     the stack is addressed as one flat (T·R, s) row space (global row id =
-    t·R + idx) and pooled through the double-buffered DMA core in per-
-    table blocks, so tables of production size run at streaming bandwidth
+    t·R + idx) and pooled through the DMA-ring core in per-table blocks
+    (lane tiles under auto), so tables of production size run at streaming bandwidth
     instead of failing the residency assumption.  ``pool_mode`` picks the
     scalar walk or the unrolled vector walk in BOTH regimes; ``plan``
     consumes a :func:`stacked_stream_plan` built off the critical path.

@@ -60,7 +60,7 @@ def embedding_bag_stacked_op(tables, idx, mask, *, impl: str = "pallas",
                              plan_method: str = "auto"):
     """(T,R,s) stacked embedding bags -> (B,T,s); the model hot path.
     ``row_block`` 0 = auto (VMEM-resident when the table block fits, the
-    double-buffered DMA stream otherwise); ``pool_mode`` scalar walk vs
+    lane-tile DMA stream otherwise); ``pool_mode`` scalar walk vs
     unrolled vector walk; ``plan`` a precomputed StreamPlan (streamed
     regime, built off the critical path); the kernel pads partial batch
     tiles internally, so any B works."""

@@ -139,7 +139,7 @@ def apply_emb(tables, idx, mask, backend: str = "ref",
     through VMEM and never builds that intermediate, and 'interpret' runs
     that kernel body through the Pallas interpreter.  ``row_block``
     (cfg.row_block) picks the kernel regime: 0 auto — VMEM-resident table
-    blocks when they fit, double-buffered DMA row streaming otherwise;
+    blocks when they fit, lane-tile DMA row streaming otherwise;
     ``pool_mode`` (cfg.pool_mode) the scalar walk vs the unrolled
     vector walk (DESIGN.md §1).  ``plan`` consumes a precomputed StreamPlan
     (kernels.embedding_bag.stacked_stream_plan / build_forward_plans) so
@@ -411,7 +411,7 @@ def forward_distributed(params, cfg: DLRMConfig, dense, idx, mask, *,
     ``row_block`` (default cfg.row_block)
     selects the embedding-bag kernel regime on BOTH pooling paths
     (DESIGN.md §1: 0 auto — VMEM-resident table blocks when they fit,
-    double-buffered DMA row streaming otherwise); ``pool_mode`` (default
+    lane-tile DMA row streaming otherwise); ``pool_mode`` (default
     cfg.pool_mode) the scalar vs chunked-vector pooling loop.
 
     ``plan`` consumes the per-(member, microbatch) StreamPlans of

@@ -1,13 +1,15 @@
 """The DMA-streamed embedding-bag kernel (DESIGN.md §1): interpret-mode
-parity of the row-blocked, double-buffered streaming core against the
-pure-jnp oracles at rows >> row_block — bit-for-bit in f32, including
-non-divisible row counts / batch sizes and indices landing exactly on block
-boundaries — plus the row_block resolution policy, the ragged-row form,
-the scalar-vs-vector pool modes, the counting-sort stream plan, and the
+parity of the row-blocked streaming core and its ring of in-flight DMAs
+against the pure-jnp oracles at rows >> row_block — bit-for-bit in f32,
+including non-divisible row counts / batch sizes and indices landing
+exactly on block boundaries — plus the row_block resolution policy (lane
+tiles under auto), the ragged-row form, the scalar-vs-vector pool modes,
+both stream-plan builders against a numpy recount, and the
 precomputed-plan path (plan built off the critical path, consumed via
 ``plan=`` / ``forward_distributed`` / the engine's plan pipeline).
 """
 import os
+import re
 import subprocess
 import sys
 
@@ -72,7 +74,7 @@ class TestStreamedStackedParity:
     @pytest.mark.parametrize("r,rb", [(1000, 192), (40_000, 4096),
                                       (100_000, 8192)])
     def test_dma_pipeline_bit_exact_vs_ref(self, r, rb):
-        # the actual make_async_copy double-buffer pipeline, executed by
+        # the actual make_async_copy ring pipeline, executed by
         # the interpret machinery standalone (dma=True): the DMA schedule
         # itself must be bit-exact, not just the op-level emulation
         tbl, idx, mask = _case(2, r, 16, 16, 4, seed=r + 1, boundary_rb=rb)
@@ -133,6 +135,25 @@ class TestRowBlockPolicy:
         assert streamed
         assert 2 * rb * 64 * 4 <= eb.STREAM_VMEM_BYTES
         assert rb % 8 == 0
+
+    @pytest.mark.parametrize("r", [16_385, 262_144, 1_101_312])
+    def test_auto_streams_in_lane_tiles(self, r):
+        # a streamed stack's auto fetch unit is one 128-row lane tile,
+        # whatever the VMEM budget would hold; a 10,000-row table at the
+        # same width still resolves resident
+        assert eb.resolve_row_block(r, 64, 4, 0) == (True, eb.LANES)
+        assert eb.resolve_row_block(10_000, 64, 4, 0) == (False, 10_000)
+
+    def test_ring_slots_fit_the_stream_budget(self):
+        # lane tiles get the full ring; an explicit tall block shrinks it
+        # to what STREAM_VMEM_BYTES holds (never below two slots), and a
+        # tile that can touch fewer blocks gets no more slots than that
+        assert eb._ring_slots(6400, 128, 64, 4) == eb.STREAM_SLOTS
+        assert eb.STREAM_SLOTS * 128 * 64 * 4 <= eb.STREAM_VMEM_BYTES
+        assert eb._ring_slots(3510, 8192, 64, 4) == 2
+        assert eb._ring_slots(3510, 1 << 16, 64, 4) == 2
+        assert eb._ring_slots(1, 128, 64, 4) == 1
+        assert eb._ring_slots(5, 128, 64, 4) == 5
 
     def test_positive_row_block_forces_streaming(self):
         assert eb.resolve_row_block(100, 16, 4, 64) == (True, 64)
@@ -311,6 +332,114 @@ class TestVectorPool:
                                      interpret=True)
 
 
+def _ring_case(t, r, rb, b, hot, spread, seed):
+    """Stacked inputs whose tile touches fewer blocks than the DMA ring
+    holds (``few``: ids in the first three blocks) or many times more
+    (``many``: ids over the whole table), with ids on lane-tile edges,
+    block edges and table edges (row 0 and the last row)."""
+    ks = jax.random.split(jax.random.PRNGKey(seed), 3)
+    tbl = jax.random.normal(ks[0], (t, r, 16))
+    hi = min(3 * rb, r) if spread == "few" else r
+    idx = jax.random.randint(ks[1], (b, t, hot), 0, hi)
+    edges = [0, eb.LANES - 1, eb.LANES, rb - 1, rb, hi - 1, r - 1]
+    if spread == "few":
+        edges = [e for e in edges if e < hi]
+    for i, v in enumerate(edges):
+        idx = idx.at[i % b, i % t, (i // t) % hot].set(v)
+    mask = (jax.random.uniform(ks[2], (b, t, hot)) < 0.6) \
+        .astype(jnp.float32)
+    return tbl, idx, mask
+
+
+class TestLaneTileRing:
+    """The streamed kernel's ring of STREAM_SLOTS in-flight copies, run as
+    the real make_async_copy pipeline in interpret mode (``dma=True``):
+    bit-identical in f32 to the jnp oracle and to the schedule emulation,
+    at lane-tile and taller block heights, with fewer touched blocks than
+    slots and many more, an empty tile, all-masked bags and the rows
+    form."""
+
+    @pytest.mark.parametrize("spread", ["few", "many"])
+    @pytest.mark.parametrize("rb", [128, 256, 1024])
+    def test_ring_bit_exact_vs_ref_and_emulation(self, rb, spread):
+        tbl, idx, mask = _ring_case(2, 40_000, rb, 16, 8, spread, seed=rb)
+        plan = eb.stacked_stream_plan(2, 40_000, 16, 4, idx, row_block=rb)
+        nblk = int(plan.nblk.max())
+        if spread == "few":
+            assert nblk < eb.STREAM_SLOTS
+        else:
+            assert nblk > 4 * eb.STREAM_SLOTS
+        want = ref.embedding_bag_stacked_ref(tbl, idx, mask)
+        emu = eb.embedding_bag_stacked(tbl, idx, mask, row_block=rb,
+                                       interpret=True, dma=False)
+        assert np.array_equal(np.asarray(emu), np.asarray(want))
+        for pool in ("scalar", "vector"):
+            got = eb.embedding_bag_stacked(tbl, idx, mask, row_block=rb,
+                                           pool_mode=pool, interpret=True,
+                                           dma=True)
+            assert np.array_equal(np.asarray(got), np.asarray(want)), pool
+
+    def test_ring_of_three_slots_wraps_bit_exact(self, monkeypatch):
+        # an odd ring that wraps dozens of times over one tile's blocks
+        monkeypatch.setattr(eb, "STREAM_SLOTS", 3)
+        tbl, idx, mask = _ring_case(2, 40_000, 128, 16, 8, "many", seed=3)
+        want = ref.embedding_bag_stacked_ref(tbl, idx, mask)
+        got = eb.embedding_bag_stacked(tbl, idx, mask, row_block=128,
+                                       interpret=True, dma=True)
+        assert np.array_equal(np.asarray(got), np.asarray(want))
+
+    @pytest.mark.parametrize("rb", [128, 256, 1024])
+    def test_all_masked_bags_pool_exact_zero(self, rb):
+        tbl, idx, _ = _ring_case(2, 40_000, rb, 16, 8, "many", seed=rb + 1)
+        zero = jnp.zeros(idx.shape, jnp.float32)
+        for pool in ("scalar", "vector"):
+            got = eb.embedding_bag_stacked(tbl, idx, zero, row_block=rb,
+                                           pool_mode=pool, interpret=True,
+                                           dma=True)
+            assert float(jnp.max(jnp.abs(got))) == 0.0, pool
+
+    @pytest.mark.parametrize("rb", [128, 256, 1024])
+    def test_tile_with_no_blocks_pools_zero(self, rb):
+        # nblk = 0: the ring starts and waits for no copy, and the tile's
+        # bags come out zero in both executors; the other tiles are exact
+        tbl, idx, mask = _ring_case(2, 40_000, rb, 16, 8, "many",
+                                    seed=rb + 2)
+        plan = eb.stacked_stream_plan(2, 40_000, 16, 4, idx, batch_tile=8,
+                                      row_block=rb)
+        assert plan.nblk.shape[0] == 4
+        plan = plan._replace(nblk=plan.nblk.at[1].set(0))
+        want = ref.embedding_bag_stacked_ref(tbl, idx, mask)
+        want = np.array(want).reshape(-1, 16)
+        want[8:16] = 0.0                   # bags of tile 1
+        for kw in ({"dma": False}, {"dma": True, "pool_mode": "scalar"},
+                   {"dma": True, "pool_mode": "vector"}):
+            got = eb.embedding_bag_stacked(tbl, idx, mask, batch_tile=8,
+                                           row_block=rb, interpret=True,
+                                           plan=plan, **kw)
+            assert np.array_equal(np.asarray(got).reshape(-1, 16), want), kw
+
+    @pytest.mark.parametrize("rb", [128, 256, 1024])
+    def test_rows_form_bit_exact(self, rb):
+        ks = jax.random.split(jax.random.PRNGKey(rb), 4)
+        t, r, n, hot = 3, 40_000, 24, 6
+        tbl = jax.random.normal(ks[0], (t, r, 16))
+        tid = jax.random.randint(ks[1], (n,), 0, t)
+        idx = jax.random.randint(ks[2], (n, hot), 0, r)
+        idx = idx.at[0, 0].set(eb.LANES - 1).at[1, 0].set(eb.LANES) \
+                 .at[2, 0].set(rb).at[3, 0].set(r - 1).at[4, 0].set(0)
+        mask = (jax.random.uniform(ks[3], (n, hot)) < 0.6) \
+            .astype(jnp.float32)
+        want = ref.embedding_bag_rows_ref(tbl, tid, idx, mask)
+        emu = eb.embedding_bag_rows(tbl, tid, idx, mask, row_tile=8,
+                                    row_block=rb, interpret=True, dma=False)
+        assert np.array_equal(np.asarray(emu), np.asarray(want))
+        for pool in ("scalar", "vector"):
+            got = eb.embedding_bag_rows(tbl, tid, idx, mask, row_tile=8,
+                                        row_block=rb, pool_mode=pool,
+                                        interpret=True, dma=True)
+            assert np.array_equal(np.asarray(got), np.asarray(want)), pool
+
+
 class TestPrecomputedPlan:
     """plan= consumption: a StreamPlan built off the critical path drops
     into every executor (emulation, scalar DMA kernel, vector DMA kernel)
@@ -442,16 +571,15 @@ class TestStreamPlan:
         p = eb._stream_plan(gid, rb, rtot, nbmax, method)
         n = int(p.nblk[0, 0])
         assert n == 2                      # blocks 0 and 7 only — compacted
-        segs = [(int(p.seg0[0, j]), int(p.seg1[0, j])) for j in range(n)]
-        covered = sorted(sum([list(range(a, b)) for a, b in segs], []))
-        assert covered == list(range(6))   # every position exactly once
-        # each segment's ids fall inside its block's DMA window, and the
-        # membership mask (cum) agrees with the segment bounds
-        for j, (a, b) in enumerate(segs):
-            lo = int(p.off[0, j])
-            for q in range(a, b):
-                assert lo <= int(p.sid[0, q]) < lo + rb
-                assert int(p.cum[0, q]) == j
+        # every position belongs to exactly one compacted block, blocks in
+        # order, each block's positions one contiguous run, and each id
+        # falls inside its block's DMA window
+        cum = np.asarray(p.cum[0])
+        assert cum[0] == 0 and cum[-1] == n - 1
+        assert (np.diff(cum) >= 0).all() and (np.diff(cum) <= 1).all()
+        for q in range(6):
+            lo = int(p.off[0, cum[q]])
+            assert lo <= int(p.sid[0, q]) < lo + rb
         # pos is a bijection and inv is its inverse (staging-slot keys)
         pos = np.asarray(p.pos[0])
         assert sorted(pos.tolist()) == list(range(6))
@@ -465,14 +593,14 @@ class TestStreamPlan:
         assert (offs + 128 <= 1000).all() and (offs >= 0).all()
 
     def test_count_matches_sort_block_structure(self):
-        # same compacted blocks, offsets and segment bounds from both
-        # builders (within-block order may differ; nothing consumes it)
+        # same compacted blocks, offsets and block runs from both builders
+        # (within-block order may differ; nothing consumes it)
         gid = jax.random.randint(jax.random.PRNGKey(0), (3, 64), 0, 1000,
                                  dtype=jnp.int32)
         nbmax = min(-(-1000 // 96), 64)
         ps = eb._stream_plan(gid, 96, 1000, nbmax, "sort")
         pc = eb._stream_plan(gid, 96, 1000, nbmax, "count")
-        for f in ("off", "seg0", "seg1", "nblk"):
+        for f in ("off", "nblk", "cum"):
             assert np.array_equal(np.asarray(getattr(ps, f)),
                                   np.asarray(getattr(pc, f))), f
         # both are bijections over every tile
@@ -507,3 +635,70 @@ class TestStreamPlan:
             eb._stream_rows(tbl, gid, w, row_tile=16, rb=256,
                             interpret=True, out_dtype=jnp.float32,
                             plan=bad)
+
+    @pytest.mark.parametrize("nbmax_at", ["below_L", "at_L"])
+    @pytest.mark.parametrize("ids", ["random", "skewed", "one_block"])
+    def test_sort_plan_matches_numpy_recount(self, ids, nbmax_at):
+        tiles, L, rb, t = 3, 64, 128, 2
+        rows = 1024 if nbmax_at == "below_L" else 100_000
+        nb_total = t * -(-rows // rb)
+        nbmax = min(nb_total, L)
+        assert (nbmax < L) == (nbmax_at == "below_L")
+        rng = np.random.default_rng(len(ids) + rows)
+        if ids == "random":
+            local = rng.integers(0, rows, (tiles, L))
+        elif ids == "skewed":
+            local = np.minimum(rng.zipf(1.05, (tiles, L)) - 1, rows - 1)
+        else:
+            local = rng.integers(2 * rb, 3 * rb, (tiles, L))
+        gid = (rng.integers(0, t, (tiles, L)) * rows + local) \
+            .astype(np.int32)
+        p = eb._stream_plan(jnp.asarray(gid), rb, t * rows, nbmax, "sort",
+                            rows)
+        for i in range(tiles):
+            sid = np.sort(gid[i])
+            blk = (sid // rows) * -(-rows // rb) + (sid % rows) // rb
+            starts = np.flatnonzero(np.r_[True, blk[1:] != blk[:-1]])
+            n = len(starts)
+            ends = np.r_[starts[1:], L]
+            bid = blk[starts]
+            nbt = -(-rows // rb)
+            off = (bid // nbt) * rows + np.clip((bid % nbt) * rb, 0,
+                                                rows - rb)
+            pad = np.zeros(nbmax - n, np.int64)
+            assert int(p.nblk[i, 0]) == n
+            assert np.array_equal(np.asarray(p.sid[i]), sid)
+            assert np.array_equal(np.asarray(p.off[i]), np.r_[off, pad])
+            assert np.array_equal(np.asarray(p.cum[i]),
+                                  np.repeat(np.arange(n), ends - starts))
+            pos = np.asarray(p.pos[i])
+            assert np.array_equal(gid[i][pos], sid)
+            assert np.array_equal(np.asarray(p.inv[i])[pos], np.arange(L))
+        if ids == "one_block":
+            assert (np.asarray(p.nblk) <= t).all()
+
+    @pytest.mark.parametrize("rb", [128, 8192])
+    def test_sort_plan_lowers_without_while(self, rb):
+        # block runs come from a cumsum and offsets from a second sort, not
+        # a searchsorted loop: nothing in the plan grows with nbmax, and
+        # the plan holds no batched gather either
+        rows = 1_101_312
+        gid = jax.ShapeDtypeStruct((52, 6400), jnp.int32)
+        nbmax = min(26 * -(-rows // rb), 6400)
+        hlo = jax.jit(lambda g: eb._stream_plan(
+            g, rb, 26 * rows, nbmax, "sort", rows)).lower(gid).as_text()
+        assert "sort" in hlo and not re.search(r"\bwhile\(", hlo)
+        assert "gather" not in hlo and "scatter" not in hlo
+
+    @pytest.mark.parametrize("rb", [128, 1024])
+    def test_fetch_bytes_counts_touched_units(self, rb):
+        t, r, s, b, hot = 3, 40_000, 16, 24, 5
+        idx = jax.random.randint(jax.random.PRNGKey(rb), (b, t, hot), 0, r)
+        plan = eb.stacked_stream_plan(t, r, s, 4, idx, batch_tile=8,
+                                      row_block=rb)
+        # numpy: distinct (table, block) pairs per 8-bag tile
+        gid = (np.arange(t)[None, :, None] * r + np.asarray(idx)) \
+            .reshape(-1, 8 * hot)
+        units = sum(len(np.unique(g // r * (r // rb + 1) + g % r // rb))
+                    for g in gid)
+        assert eb.fetch_bytes(plan, s, 4) == (units, units * rb * s * 4)

@@ -13,6 +13,7 @@ entries cannot be read back without a chip.
 """
 import functools
 import os
+import re
 
 import numpy as np
 import pytest
@@ -131,3 +132,15 @@ def test_one_chip_serving_step_compiles(topo):
                      _shape(rep, (B, t_pad, cfg.max_hot), jnp.int32),
                      _shape(rep, (B, t_pad, cfg.max_hot)))
     _assert_native_kernel_no_stack_copy(c)
+
+
+def test_stream_plan_compiles_without_while(one_chip):
+    """One microbatch's stream plan at Kaggle widths under the auto
+    policy (lane tiles, 52 tiles of 6,400 indices): block runs and
+    offsets come from sorts and a cumsum, so the TPU program holds no
+    ``while`` loop and no gather."""
+    plan_of = functools.partial(eb.stacked_stream_plan, T, R, S_DIM, 4)
+    c = _compile(plan_of, _shape(one_chip, (B // 4, T, HOT), jnp.int32))
+    txt = c.as_text()
+    assert " sort(" in txt and not re.search(r"\bwhile\(", txt)
+    assert not re.search(r"\b(gather|scatter)\(", txt)
