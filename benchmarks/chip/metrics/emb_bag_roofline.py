@@ -6,8 +6,6 @@ kernel's device time summed over chips x the HBM bandwidth.  Pooling is
 bound by bytes, so the byte roof is its roofline."""
 import numpy as np
 
-import counts
-
 # the kernel's device events.  A trace names each op by its HLO instruction;
 # XLA names the Pallas pooling kernel's custom call after the jitted wrapper
 # of kernels/ops.py that calls it ("embedding_bag_stacked_op.8"), and no
@@ -24,6 +22,6 @@ def read(run):
     if ns <= 0:
         return None
     served = np.isin(w.flush_of, list(w.traced))
-    useful = float(counts.pooling_bytes(run.cell["config"],
-                                        run.valid[served]).sum())
+    useful = float(run.cell["arch"].pooling_bytes(
+        run.cell["config"], run.valid[served]).sum())
     return 100.0 * useful / (ns * 1e-9 * run.peaks["hbm_bytes_per_s"])

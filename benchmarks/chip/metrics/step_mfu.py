@@ -1,10 +1,9 @@
-"""Model FLOPs of the served requests of the traced flushes (both MLPs, the
-dot interaction and the pooling adds, counted from shapes) over
-chips x bf16 peak x the device time of those flushes (busy time inside
-each flush's host span, averaged over the chips), in %."""
+"""Model FLOPs of the served requests of the traced flushes (the
+architecture's dense part and the pooling adds, counted from shapes by its
+``request_flops``) over chips x bf16 peak x the device time of those
+flushes (busy time inside each flush's host span, averaged over the
+chips), in %."""
 import numpy as np
-
-import counts
 
 
 def read(run):
@@ -15,7 +14,7 @@ def read(run):
     if busy_ns <= 0:
         return None
     served = np.isin(w.flush_of, list(w.traced))
-    flops = float(counts.request_flops(run.cell["config"],
-                                       run.valid[served]).sum())
+    flops = float(run.cell["arch"].request_flops(
+        run.cell["config"], run.valid[served]).sum())
     peak = run.peaks["bf16_flops_per_s"]
     return 100.0 * flops / (run.chips * peak * busy_ns * 1e-9)

@@ -1,18 +1,15 @@
-"""Plain reference DLRM forward, and its lower-precision control.
+"""Plain reference of a DLRM-family model, and its lower-precision control:
+the parts every architecture (``archs/*.py``) shares.
 
-Written from the model's definition (Naumov et al., arXiv:1906.00091, the
-reference ``dlrm_s_pytorch.py``), in ``jax.numpy`` with no kernel, cache,
-exchange or pipeline, and importing nothing of the program:
+Written in ``jax.numpy`` with no kernel, cache, exchange or pipeline, and
+importing nothing of the program.  Bags are sum-pooled table by table on
+the device that holds the table, in float32:
 
-  z0     = bottom MLP(dense)                      ReLU between layers
-  e_t    = sum_h mask[t, h] * tables[t, idx[t, h]]  sum-pooled bag per table
-  z      = [z0, e_0, ..., e_{T-1}]                (T + 1, s)
-  inter  = z_i . z_j for i > j, row-major         (lower triangle)
-  ctr    = sigmoid(top MLP([z0, inter]))          ReLU between layers
+  e_t    = sum_h mask[t, h] * tables[t, idx[t, h]]
 
-Bags are pooled table by table on the device that holds the table, in
-float32; the dense part runs on the first device.  ``precision`` names how
-the matrix products are computed:
+and an architecture's dense part (its MLPs and interaction) turns the dense
+features and the pooled bags into CTRs on the first device.  ``precision``
+names how the matrix products are computed:
   "highest"  float32 products (the configuration's stated precision);
   "high"     the control: each float32 operand split into a bfloat16 high
              part and a bfloat16 low part, and the three products hi*hi,
@@ -21,8 +18,6 @@ the matrix products are computed:
              the same on any backend.
 """
 from __future__ import annotations
-
-import functools
 
 import jax
 import jax.numpy as jnp
@@ -58,17 +53,6 @@ def _mlp(layers, x, precision):
         if i < len(layers) - 1:
             x = jax.nn.relu(x)
     return x
-
-
-@functools.partial(jax.jit, static_argnames=("precision", "n_tables"))
-def _dense_part(bot, top, dense, pooled, *, precision: str, n_tables: int):
-    """CTRs from dense features (B, n_dense) and pooled bags (B, T_pad, s)."""
-    z0 = _mlp(bot, dense, precision)
-    z = jnp.concatenate([z0[:, None, :], pooled[:, :n_tables]], axis=1)
-    zz = _dot(z, z, precision, "bfs,bgs->bfg")
-    ii, jj = np.tril_indices(z.shape[1], k=-1)
-    top_in = jnp.concatenate([z0, zz[:, ii, jj]], axis=-1)
-    return jax.nn.sigmoid(_mlp(top, top_in, precision)[:, 0])
 
 
 @jax.jit
@@ -115,24 +99,27 @@ def pooled_bags(tables, idx: np.ndarray, mask: np.ndarray,
     return out
 
 
-def ctr(params, dense: np.ndarray, idx: np.ndarray, mask: np.ndarray, *,
-        n_tables: int, precision: str = "highest",
+def ctr(dense_part, params, dense: np.ndarray, idx: np.ndarray,
+        mask: np.ndarray, *, n_tables: int, precision: str = "highest",
         block: int = 512) -> np.ndarray:
     """Reference CTRs of the requests (dense, idx, mask), in blocks of
-    ``block`` rows (the last block padded), as a float32 array."""
+    ``block`` rows (the last block padded), as a float32 array.
+    ``dense_part(rest, dense, pooled, *, precision, n_tables)`` is the
+    architecture's jitted dense part; ``rest`` is ``params`` without the
+    tables, on the first device."""
     if precision not in PRECISIONS:
         raise ValueError(f"unknown precision {precision!r}")
     dev = jax.devices()[0]
-    bot = jax.device_put(params["bot"], dev)
-    top = jax.device_put(params["top"], dev)
+    rest = jax.device_put({k: v for k, v in params.items()
+                           if k != "tables"}, dev)
     n = dense.shape[0]
     out = np.empty(n, np.float32)
     for lo in range(0, n, block):
         hi = min(lo + block, n)
         sel = np.arange(lo, lo + block).clip(max=hi - 1)
         pooled = pooled_bags(params["tables"], idx[sel], mask[sel], n_tables)
-        got = _dense_part(bot, top, jax.device_put(dense[sel], dev),
-                          jax.device_put(pooled, dev), precision=precision,
-                          n_tables=n_tables)
+        got = dense_part(rest, jax.device_put(dense[sel], dev),
+                         jax.device_put(pooled, dev), precision=precision,
+                         n_tables=n_tables)
         out[lo:hi] = np.asarray(got)[:hi - lo]
     return out

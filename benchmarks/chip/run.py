@@ -47,16 +47,17 @@ for _p in (HERE, os.path.join(ROOT, "src")):
 import numpy as np  # noqa: E402
 
 import harness  # noqa: E402
-import reference  # noqa: E402
 import spec  # noqa: E402
 import xplane  # noqa: E402
 import traffic  # noqa: E402
-import weights  # noqa: E402
 
 # JAX's persistent compilation cache: a fixed directory inside the checkout
 CACHE_DIR = os.path.join(HERE, ".cache", "jax")
 # platforms whose traces hold the device planes every per-layer metric reads
 TRACED_PLATFORMS = ("tpu",)
+# JAX's event around every executable it builds: a compilation or a load from
+# the persistent cache
+BACKEND_COMPILE = "/jax/core/compile/backend_compile_duration"
 
 
 class NoAccelerator(RuntimeError):
@@ -91,16 +92,23 @@ def configure_jax(cfg: dict) -> None:
     jax.config.update("jax_default_matmul_precision", cfg["matmul_precision"])
 
 
-def program_config(cfg: dict):
-    """The program's ``DLRMConfig`` for a configuration file."""
-    from repro.configs.base import DLRMConfig
-    return DLRMConfig(
-        name=cfg["name"], n_dense_features=cfg["n_dense_features"],
-        table_sizes=tuple(cfg["table_sizes"]), embed_dim=cfg["embed_dim"],
-        bottom_mlp=tuple(cfg["bottom_mlp"]), top_mlp=tuple(cfg["top_mlp"]),
-        max_hot=cfg["max_hot"], arch_interaction_op=cfg["interaction"],
-        dtype=cfg["dtype"], sparse_backend=cfg["sparse_backend"],
-        exchange_pipeline=cfg["exchange_pipeline"])
+class CompileCount:
+    """Counts the executables JAX builds while the ``with`` block runs
+    (``n``): warm-up should leave none for the measured window."""
+
+    def __enter__(self):
+        import jax
+        self.n = 0
+        jax.monitoring.register_event_duration_secs_listener(self._seen)
+        return self
+
+    def __exit__(self, *exc):
+        import jax
+        jax.monitoring.unregister_event_duration_listener(self._seen)
+
+    def _seen(self, event, duration, **kwargs):
+        if event == BACKEND_COMPILE:
+            self.n += 1
 
 
 def make_mesh(devices):
@@ -113,9 +121,9 @@ def make_pool(cell: dict, seed: int) -> traffic.Pool:
     return traffic.make_pool(
         cfg["table_sizes"], cfg["n_dense_features"], cfg["max_hot"],
         cell["workload"]["pool"], mode=mix["mode"],
-        t_pad=weights.stack_shape(cfg)[0],
+        t_pad=cell["arch"].stack_shape(cfg)[0],
         zipf_alpha=mix.get("zipf_alpha", 1.05), phase=mix.get("phase", 0),
-        seed=seed)
+        hot=mix.get("hot"), seed=seed)
 
 
 def arrivals(cell: dict, seed: int, seconds: float,
@@ -134,7 +142,8 @@ def make_frontend(cell: dict, params):
     from repro.serving.engine import DLRMEngine
     from repro.serving.frontend import ServingFrontend
     cfg, fp = cell["config"], cell["workload"]["frontend"]
-    engine = DLRMEngine(params, program_config(cfg), batch_size=cfg["batch"],
+    engine = DLRMEngine(params, cell["arch"].program_config(cfg),
+                        batch_size=cfg["batch"],
                         bound=cfg["bound"], microbatches=cfg["microbatches"])
     return ServingFrontend(engine, slo_s=fp["slo_s"],
                            admission=fp["admission"], shed=fp["shed"],
@@ -154,11 +163,10 @@ def memory_peak(devices) -> int:
 def reference_ctrs(params, cell: dict, pool: traffic.Pool, ids: np.ndarray,
                    precision: str = "highest") -> np.ndarray:
     """Reference CTRs of the pool entries ``ids``."""
-    return reference.ctr(params, pool.dense[ids], pool.idx[ids],
-                         pool.mask[ids],
-                         n_tables=len(cell["config"]["table_sizes"]),
-                         precision=precision,
-                         block=reference_block(cell["config"]))
+    arch, cfg = cell["arch"], cell["config"]
+    return arch.ctr(params, pool.dense[ids], pool.idx[ids], pool.mask[ids],
+                    cfg=cfg, precision=precision,
+                    block=arch.reference_block(cfg))
 
 
 def ctr_gaps(params, cell: dict, pool: traffic.Pool, order: np.ndarray,
@@ -168,12 +176,6 @@ def ctr_gaps(params, cell: dict, pool: traffic.Pool, order: np.ndarray,
     want = reference_ctrs(params, cell, pool, u)
     return np.abs(served.astype(np.float64)
                   - want[np.searchsorted(u, order)].astype(np.float64))
-
-
-def reference_block(cfg: dict) -> int:
-    """Rows per reference block: about 51,200 gathered rows a block, at
-    most 8,192 requests."""
-    return max(512, min(8192, (51200 // cfg["max_hot"]) // 512 * 512))
 
 
 @dataclasses.dataclass
@@ -221,7 +223,7 @@ def serve_cell(cell: dict, seed: int, seconds: float, devices: list,
     cfg, wl = cell["config"], cell["workload"]
     configure_jax(cfg)
     mesh = make_mesh(devices)
-    params = weights.make_params(seed, cfg, mesh)
+    params = cell["arch"].make_params(seed, cfg, mesh)
     pool = make_pool(cell, seed)
     due, order = arrivals(cell, seed, seconds, rate)
     with mesh_context(mesh):
@@ -236,11 +238,12 @@ def serve_cell(cell: dict, seed: int, seconds: float, devices: list,
         gc.freeze()
         with tempfile.TemporaryDirectory(prefix="bench-trace-") as tmp:
             tdir = keep_trace or tmp
-            win = harness.serve(
-                fe, pool, due, order, seconds,
-                trace_dir=tdir if trace else None,
-                trace_at=min(wl["trace_at_s"], seconds / 4),
-                trace_s=wl["trace_s"])
+            with CompileCount() as compiled:
+                win = harness.serve(
+                    fe, pool, due, order, seconds,
+                    trace_dir=tdir if trace else None,
+                    trace_at=min(wl["trace_at_s"], seconds / 4),
+                    trace_s=wl["trace_s"])
             peak = memory_peak(devices)
             del fe
             gc.unfreeze()
@@ -253,6 +256,7 @@ def serve_cell(cell: dict, seed: int, seconds: float, devices: list,
     print(f"generator lateness ms p50 {late_ms[0]:.4f} p99 "
           f"{late_ms[1]:.4f} max {late_ms[2]:.4f}; flushes "
           f"{win.flushes.shape[0]}", flush=True)
+    log(f"compiles in window: {compiled.n}")
     return Served(params=params, pool=pool, order=order, window=win,
                   setup_s=setup_s, memory_peak_bytes=peak, trace=red)
 

@@ -8,7 +8,6 @@ import pytest
 HERE = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 sys.path.insert(0, HERE)
 
-import counts  # noqa: E402
 import harness  # noqa: E402
 import run  # noqa: E402
 import spec  # noqa: E402
@@ -18,6 +17,7 @@ from xplane import Event  # noqa: E402
 CFG = {"table_sizes": [10, 20], "n_dense_features": 4, "embed_dim": 8,
        "bottom_mlp": [16, 8], "top_mlp": [12, 1], "dtype": "float32"}
 PEAKS = {"bf16_flops_per_s": 1e12, "hbm_bytes_per_s": 1e9}
+DLRM = spec.arch({"arch": "dlrm"})
 
 
 def toy_run(trace=True):
@@ -37,7 +37,7 @@ def toy_run(trace=True):
                           Event("all-to-all.1", 300, 800),
                           Event("fusion.1", 700, 900)]}
     red = xplane.reduce(devices, host) if trace else None
-    return run.Run(root=spec.ROOT, cell={"config": CFG}, seed=0,
+    return run.Run(root=spec.ROOT, cell={"config": CFG, "arch": DLRM}, seed=0,
                    setup_s=12.5, window=w, valid=np.array([3, 4, 5, 6]),
                    chips=2, peaks=PEAKS, trace=red)
 
@@ -66,10 +66,10 @@ def test_device_readers_by_hand():
     # busy: device 0 100-800 (700 ns), device 1 100-900 (800 ns) of 1000
     assert read("device_idle_share", r) == pytest.approx(25.0)
     # traced flush 1 serves requests 2 and 3 (5 and 6 valid indices)
-    flops = counts.request_flops(CFG, [5, 6]).sum()
+    flops = DLRM.request_flops(CFG, [5, 6]).sum()
     assert read("step_mfu", r) == pytest.approx(
         100 * flops / (2 * 1e12 * 750e-9))
-    useful = counts.pooling_bytes(CFG, [5, 6]).sum()
+    useful = DLRM.pooling_bytes(CFG, [5, 6]).sum()
     assert read("emb_bag_roofline", r) == pytest.approx(
         100 * useful / (700e-9 * 1e9))
     # exposed: device 0 600-800 (200 ns), device 1 300-700 (400 ns); one

@@ -107,6 +107,7 @@ def test_one_window_end_to_end(tmp_path, monkeypatch, capsys):
     assert res["checks"]["unserved"] == {"value": 0, "limit": 0}
     assert err[-1].startswith("[bench] check unserved")
     assert err[-2].startswith("[bench] check ctr_gap")
+    assert "[bench] compiles in window: 0" in err
 
 
 def test_traced_window_reports_per_layer(tmp_path, monkeypatch, capsys):
@@ -167,7 +168,6 @@ def test_control_in_the_programs_place_is_not_correct(
     passes, the precision below the configuration's -- served in place of
     the program's CTRs, at the cell's widths (small tables) and the cell's
     own limit: ``correct`` comes out false."""
-    import reference
     with open(os.path.join(HERE, "workloads", cell + ".json")) as f:
         limit = json.load(f)["ctr_gap_limit"]
     stub_chip(monkeypatch)
@@ -176,9 +176,8 @@ def test_control_in_the_programs_place_is_not_correct(
     def control_served(c, *a, **k):
         sv = real(c, *a, **k)
         p, u = sv.pool, np.unique(sv.order)
-        lo = reference.ctr(sv.params, p.dense[u], p.idx[u], p.mask[u],
-                           n_tables=len(c["config"]["table_sizes"]),
-                           precision="high", block=256)
+        lo = c["arch"].ctr(sv.params, p.dense[u], p.idx[u], p.mask[u],
+                           cfg=c["config"], precision="high", block=256)
         sv.window.ctr[:] = lo[np.searchsorted(u, sv.order)]
         return sv
 

@@ -171,7 +171,8 @@ def chip_run(path, flushes):
         cfg = json.load(f)
     with open(os.path.join(HERE, "peaks.json")) as f:
         peaks = json.load(f)["TPU v5 lite"]
-    return run.Run(root=spec.ROOT, cell={"config": cfg}, seed=0,
+    return run.Run(root=spec.ROOT, cell={"config": cfg,
+                                         "arch": spec.arch(cfg)}, seed=0,
                    setup_s=1.0, window=w, valid=np.full(n, 50), chips=1,
                    peaks=peaks, trace=red)
 

@@ -21,7 +21,7 @@ import dataclasses
 
 import numpy as np
 
-MODES = ("uniform", "hetero", "powerlaw", "powerlaw_hetero", "drift")
+MODES = ("uniform", "hetero", "powerlaw", "powerlaw_hetero", "drift", "fixed")
 
 
 @dataclasses.dataclass(frozen=True)
@@ -53,12 +53,17 @@ def table_heat(n_tables: int, phase: int, *, seed: int = 0) -> np.ndarray:
 
 def make_pool(table_sizes, n_dense: int, max_hot: int, n: int, *,
               mode: str, t_pad: int, zipf_alpha: float = 1.05,
-              phase: int = 0, seed: int = 0) -> Pool:
+              phase: int = 0, hot=None, seed: int = 0) -> Pool:
     """``n`` requests of the mix ``mode`` (``make_batch`` of the program's
-    data module, one batch of ``n`` rows at step 0)."""
+    data module, one batch of ``n`` rows at step 0).  ``fixed``, which the
+    program's module lacks, gives every bag of table t exactly ``hot[t]``
+    ids, uniform over the table (MLPerf DLRMv2's ``multi_hot_sizes`` with
+    the ``uniform`` distribution); the pool is ``max(hot)`` wide."""
     if mode not in MODES:
         raise ValueError(f"unknown traffic mode {mode!r}; have {MODES}")
     rng = np.random.default_rng(np.random.SeedSequence([seed, 0]))
+    if mode == "fixed":
+        return _fixed_pool(rng, table_sizes, n_dense, n, t_pad, hot)
     t = len(table_sizes)
     ragged = mode in ("hetero", "powerlaw_hetero", "drift")
     hot = max_hot if ragged else 1
@@ -83,6 +88,21 @@ def make_pool(table_sizes, n_dense: int, max_hot: int, n: int, *,
         mask[:, ti] = (np.arange(hot)[None, :]
                        < counts[:, None]).astype(np.float32)
     rng.random(n)          # the labels draw of make_batch: keeps the stream
+    return Pool(dense=dense, idx=idx, mask=mask)
+
+
+def _fixed_pool(rng, table_sizes, n_dense: int, n: int, t_pad: int,
+                hot) -> Pool:
+    if hot is None or len(hot) != len(table_sizes):
+        raise ValueError(f"mode 'fixed' needs one bag size a table: "
+                         f"{len(table_sizes)} tables, hot {hot!r}")
+    dense = rng.standard_normal((n, n_dense), dtype=np.float32)
+    idx = np.zeros((n, t_pad, max(hot)), np.int32)
+    mask = np.zeros((n, t_pad, max(hot)), np.float32)
+    for ti, (rows, h) in enumerate(zip(table_sizes, hot)):
+        idx[:, ti, :h] = rng.integers(0, rows, size=(n, h), dtype=np.int32)
+        mask[:, ti, :h] = 1.0
+    rng.random(n)          # the labels draw, as in the other modes
     return Pool(dense=dense, idx=idx, mask=mask)
 
 

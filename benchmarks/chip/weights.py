@@ -1,4 +1,5 @@
-"""Random DLRM weights made on the device from the seed, in one jitted call.
+"""Random weights made on the device from the seed, in one jitted call:
+the parts every DLRM-family architecture (``archs/*.py``) shares.
 
 The benchmark makes the weights itself, so the reference never takes an
 array the program made.  They come in the layout the program serves from:
@@ -6,7 +7,7 @@ array the program made.  They come in the layout the program serves from:
   tables  (t_pad, r_pad, s) in the configuration's dtype; t_pad rounds the
           table count up to a multiple of the chips, r_pad the largest table
           up to whole 128-row lane tiles; sharded table-wise over ``model``
-  bot/top [{"kernel": (d_in, d_out), "bias": (d_out,)}, ...], replicated
+  MLPs    [{"kernel": (d_in, d_out), "bias": (d_out,)}, ...], replicated
 
 Tables are uniform in [-a, a] with a = 2 sqrt(3) / s (standard deviation
 2/s); kernels truncated normal with He's scale (2 / d_in) ** 0.5; biases
@@ -38,45 +39,32 @@ def stack_shape(cfg: dict) -> tuple:
     return t_pad, r_pad, cfg["embed_dim"]
 
 
-def mlp_dims(cfg: dict) -> tuple:
-    f = len(cfg["table_sizes"]) + 1
-    bot = (cfg["n_dense_features"], *cfg["bottom_mlp"])
-    top = (f * (f - 1) // 2 + cfg["embed_dim"], *cfg["top_mlp"])
-    return bot, top
-
-
-def builder(cfg: dict, mesh):
-    """The jitted ``key -> params`` of ``cfg``, placed on ``mesh``."""
-    shape = stack_shape(cfg)
-    dt = jnp.dtype(cfg["dtype"])
-    bot_dims, top_dims = mlp_dims(cfg)
+def tables(key, cfg: dict):
+    """The table stack of ``cfg``, uniform in [-a, a]."""
     a = 2.0 * float(np.sqrt(3.0)) / cfg["embed_dim"]
+    return jax.random.uniform(key, stack_shape(cfg), jnp.dtype(cfg["dtype"]),
+                              -a, a)
 
-    def mlp(key, dims):
-        out = []
-        for i, k in enumerate(jax.random.split(key, len(dims) - 1)):
-            kk, kb = jax.random.split(k)
-            w = jax.random.truncated_normal(kk, -2.0, 2.0,
-                                            (dims[i], dims[i + 1]))
-            out.append({"kernel": (w * (2.0 / dims[i]) ** 0.5).astype(dt),
-                        "bias": (0.1 * jax.random.normal(
-                            kb, (dims[i + 1],))).astype(dt)})
-        return out
 
-    def build(key):
-        kt, kb, ktop = jax.random.split(key, 3)
-        tables = jax.random.uniform(kt, shape, dt, -a, a)
-        return {"tables": tables, "bot": mlp(kb, bot_dims),
-                "top": mlp(ktop, top_dims)}
+def mlp(key, dims, dtype) -> list:
+    """Layers ``dims[0] -> dims[1] -> ...``: a split of ``key`` a layer,
+    each split again into its kernel's key and its bias's key."""
+    out = []
+    for i, k in enumerate(jax.random.split(key, len(dims) - 1)):
+        kk, kb = jax.random.split(k)
+        w = jax.random.truncated_normal(kk, -2.0, 2.0, (dims[i], dims[i + 1]))
+        out.append({"kernel": (w * (2.0 / dims[i]) ** 0.5).astype(dtype),
+                    "bias": (0.1 * jax.random.normal(
+                        kb, (dims[i + 1],))).astype(dtype)})
+    return out
 
+
+def placed(build, mesh):
+    """``build`` (key -> params with a ``tables`` stack) jitted so that the
+    stack is split table-wise over ``model`` and the rest replicated."""
     rep = NamedSharding(mesh, PartitionSpec())
     shardings = jax.tree.map(lambda _: rep,
                              jax.eval_shape(build, seed_key(0)))
     shardings["tables"] = NamedSharding(
         mesh, PartitionSpec("model", None, None))
     return jax.jit(build, out_shardings=shardings)
-
-
-def make_params(seed: int, cfg: dict, mesh):
-    """The parameters of ``cfg`` for ``seed``, placed on ``mesh``."""
-    return builder(cfg, mesh)(seed_key(seed))
